@@ -1,0 +1,16 @@
+"""Kernels: band-kernel device time in the consensus programs, ms per Mb.
+
+Device milliseconds of the Pallas band kernels' operations
+(``fw_dirs_band`` and ``fw_dirs_band_tile``, ``ops/pallas/band_kernel.py``)
+inside the consensus programs (every program but the overlap
+aligner's: ``sched_rounds`` and the others of ``ops/device_poa.py``,
+``sched/``, ``ops/redo.py``), from the profiler trace, per Mb of
+draft polished in the traced job."""
+
+from benchmark import trace_reduce
+
+
+def read(ctx):
+    secs = trace_reduce.kernel_seconds(ctx["trace"], r"^fw_dirs_band",
+                                       overlap=False)
+    return None if secs is None else 1e3 * secs / ctx["draft_mb"]
