@@ -871,10 +871,9 @@ def main():
         # rate is untouched). New in 7: the ovl_* extras ride along —
         # ovl_device_jobs / ovl_native_jobs / ovl_tiles_exec /
         # ovl_device_fraction from the tiled ultralong overlap path
-        # (ops/ovl_align.py round 7) and align_phase_seconds, the
-        # polisher's wall-clock alignment phase — all absent on a bench
-        # that never aligned overlaps, populated when the genome bench
-        # path runs a polish in-process.
+        # (ops/ovl_align.py round 7) — all absent on a bench that never
+        # aligned overlaps, populated when the genome bench path runs a
+        # polish in-process.
         # metric_version 6: same primary value as versions 2-5
         # (compute-only windows/s of a warm production chunk). New in 6:
         # the e2e rate is the MEDIAN of RACON_TPU_BENCH_E2E_REPS reps

@@ -315,6 +315,14 @@ def _iter_metric_keys(ctx: Context, path: str) -> \
             for key in _str_keys(node.args[0]):
                 if _KEY_RE.match(key):
                     yield node.lineno, key
+        # timed_span(kind, name, ...) records <kind>_seconds_<name>.
+        elif isinstance(node, ast.Call) and \
+                _func_name(node) == "timed_span" and len(node.args) >= 2:
+            kind, name = (_str_key(a) for a in node.args[:2])
+            if kind is not None and name is not None:
+                key = f"{kind}_seconds_{name}"
+                if _KEY_RE.match(key):
+                    yield node.lineno, key
         # The reg.apply(mutator) convention in obs/metrics.py: the
         # mutator's dict parameter is named ``v`` and its subscript
         # stores are recorded keys (docs/ANALYSIS.md).
@@ -343,9 +351,11 @@ def check_metrics_contract(ctx: Context) -> Iterator[Finding]:
     specs = ctx.metric_specs()
     patterns = [s[0] for s in specs]
 
+    recorded = set()
     for path in ctx.scoped("racon_tpu/"):
         rel = ctx.rel(path)
         for lineno, key in _iter_metric_keys(ctx, path):
+            recorded.add(key)
             if key.startswith("_"):
                 continue  # internal, excluded from snapshots
             if ctx.pragma(path, lineno, "metric-ok"):
@@ -378,8 +388,10 @@ def check_metrics_contract(ctx: Context) -> Iterator[Finding]:
     from racon_tpu.obs import metrics as metrics_mod
     for pattern, kind, doc_token in specs:
         token = pattern.split("*", 1)[0]
-        # MET002: spec with no producer anywhere in racon_tpu/.
-        if token and token not in blob:
+        # MET002: spec with no producer anywhere in racon_tpu/ (a key
+        # that only a timed_span call spells out counts as produced).
+        if token and token not in blob and \
+                not any(_key_matches(k, pattern) for k in recorded):
             yield Finding(
                 "MET002", "error", metrics_rel, spec_line(pattern),
                 f"METRIC_SPECS row {pattern!r} has no producer in "
@@ -409,19 +421,22 @@ _TRACERY = re.compile(r"(^|\.)get_tracer\(\)$")
 def _iter_span_emits(tree: ast.Module) -> \
         Iterator[Tuple[int, str, Optional[set]]]:
     """(lineno, kind, kwarg-names or None-when-splatted) for every
-    Tracer .span/.point/.emit call. Receiver heuristic: a bare
-    ``tracer``/``tr`` name or a ``get_tracer()`` call — io indexes and
-    other .span APIs don't match."""
+    Tracer .span/.point/.emit call and every obs/metrics.py
+    ``timed_span`` call (which opens a span of its first argument's
+    kind). Receiver heuristic: a bare ``tracer``/``tr`` name or a
+    ``get_tracer()`` call — io indexes and other .span APIs don't
+    match."""
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):
             continue
         f = node.func
-        if not isinstance(f, ast.Attribute) or \
-                f.attr not in ("span", "point", "emit"):
-            continue
-        recv = _unparse(f.value)
-        if not (recv in ("tracer", "tr") or _TRACERY.search(recv)):
-            continue
+        if _func_name(node) != "timed_span":
+            if not isinstance(f, ast.Attribute) or \
+                    f.attr not in ("span", "point", "emit"):
+                continue
+            recv = _unparse(f.value)
+            if not (recv in ("tracer", "tr") or _TRACERY.search(recv)):
+                continue
         if not node.args:
             continue
         kind = _str_key(node.args[0])

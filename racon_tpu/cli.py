@@ -149,6 +149,19 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
+    """Run racon_tpu with ``argv`` (default: the process's arguments);
+    returns the exit code. A ``--trace`` tracer lives for this call
+    alone: once it is finished, the process tracer from before the call
+    is back, so a later in-process call is not traced by it."""
+    from racon_tpu.obs import trace as obs_trace
+    prev = obs_trace._tracer
+    try:
+        return _main(argv)
+    finally:
+        obs_trace._tracer = prev
+
+
+def _main(argv: Optional[List[str]]) -> int:
     # The autoscaler re-executes this same command line per spawned
     # worker, so keep the unparsed form around.
     raw_argv = list(argv) if argv is not None else sys.argv[1:]

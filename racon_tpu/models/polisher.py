@@ -275,8 +275,11 @@ class Polisher:
         # 4. Sequence transmute: build reverse complements where some
         # overlap needs them, free what nothing references
         # (src/polisher.cpp:339-348).
-        for i, seq in enumerate(self.sequences):
-            seq.transmute(has_name[i], has_data[i], has_reverse[i])
+        from racon_tpu.obs import metrics as obs_metrics
+        with obs_metrics.timed_span("align", "revcomp",
+                                    overlaps=len(overlaps)):
+            for i, seq in enumerate(self.sequences):
+                seq.transmute(has_name[i], has_data[i], has_reverse[i])
 
         # 5. Breaking points; PAF/MHAP overlaps need a global alignment
         # first. With a device backend the whole phase runs as batched
@@ -287,10 +290,14 @@ class Polisher:
         # fall back to the batched native call, which also serves the
         # CPU backend outright (src/polisher.cpp:351-364,
         # overlap.cpp:194-213).
-        import time as _time
-        from racon_tpu.obs import metrics as obs_metrics
-        t_align = _time.perf_counter()
+        # Each host stretch of the phase is an ``align`` span whose
+        # seconds land in ``align_seconds_<name>`` (obs/metrics.py
+        # ``timed_span``; the device ones in ops/ovl_align.py).
+        reg = obs_metrics.registry()
         pending = [o for o in overlaps if len(o.cigar) == 0]
+        reg.inc("align_bases_total",
+                sum(o.q_end - o.q_begin + o.t_end - o.t_begin
+                    for o in pending))
         if pending and self.engine.backend == "jax":
             from racon_tpu.ops.ovl_align import device_breaking_points
             # Edit-distance scoring (0, -1, -1): the reference derives
@@ -304,24 +311,38 @@ class Polisher:
             from racon_tpu.native.aligner import NativeAligner
             from racon_tpu.ops.cigar import ops_to_cigar
             from racon_tpu.ops.encode import encode_bases
-            # Edit-distance scoring, like edlib (src/overlap.cpp:198-200).
-            aligner = NativeAligner(threads=self.threads)
-            pairs = []
-            for o in pending:
-                q, t = o.alignment_operands(self.sequences)
-                pairs.append((encode_bases(bytes(q)), encode_bases(bytes(t))))
-            for o, ops in zip(pending, aligner.align_batch(pairs)):
-                o.cigar = ops_to_cigar(ops)
+            with obs_metrics.timed_span("align", "native_redo",
+                                        overlaps=len(pending),
+                                        threads=self.threads) as sp:
+                # Edit-distance scoring, like edlib
+                # (src/overlap.cpp:198-200).
+                aligner = NativeAligner(threads=self.threads)
+                pairs = []
+                for o in pending:
+                    q, t = o.alignment_operands(self.sequences)
+                    pairs.append((encode_bases(bytes(q)),
+                                  encode_bases(bytes(t))))
+                bases = sum(len(q) + len(t) for q, t in pairs)
+                sp.add(bases=bases)
+                reg.inc("align_native_bases", bases)
+                for o, ops in zip(pending, aligner.align_batch(pairs)):
+                    o.cigar = ops_to_cigar(ops)
         step = len(overlaps) // 20
-        for i, o in enumerate(overlaps):
-            o.find_breaking_points(self.sequences, self.window_length)
-            # 20-tick cap as in the reference (src/polisher.cpp:359-364).
-            if step and (i + 1) % step == 0 and (i + 1) // step <= 20:
-                log.tick("[racon_tpu::Polisher::initialize] aligning overlaps")
-        # The whole phase — device dispatch, native fallback, and the
-        # breaking-point walk — is timed as align_phase_seconds
-        # (chip_smoke.py prints it as align_s).
-        obs_metrics.record_align_phase(_time.perf_counter() - t_align)
+        with obs_metrics.timed_span("align", "bp_walk",
+                                    overlaps=len(overlaps)) as sp:
+            walked = 0
+            for i, o in enumerate(overlaps):
+                # Overlaps the device handled already hold their
+                # breaking points; the rest walk a CIGAR.
+                walked += o.breaking_points is None
+                o.find_breaking_points(self.sequences, self.window_length)
+                # 20-tick cap as in the reference
+                # (src/polisher.cpp:359-364).
+                if step and (i + 1) % step == 0 and (i + 1) // step <= 20:
+                    log.tick("[racon_tpu::Polisher::initialize] "
+                             "aligning overlaps")
+            sp.add(walked=walked)
+        reg.inc("align_walk_overlaps", walked)
         log.phase("[racon_tpu::Polisher::initialize] aligned overlaps")
         log.begin()
 

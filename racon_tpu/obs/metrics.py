@@ -39,6 +39,7 @@ already had in hand.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Dict, Optional
@@ -445,26 +446,35 @@ def record_ovl(device_jobs: int, native_jobs: int, tiles: int,
     reg.apply(_mutate)
 
 
-def record_align_phase(seconds: float,
-                       reg: Optional[MetricsRegistry] = None) -> None:
-    """Wall seconds of one polisher align phase (device dispatch +
-    native fallback + breaking-point walk; models/polisher.py phase 5).
-    Accumulates across contigs so bench extras see the whole run."""
-    reg = reg if reg is not None else _REGISTRY
-    reg.inc("align_phase_seconds", float(seconds))
-
-
 def ovl_extras(reg: Optional[MetricsRegistry] = None
                ) -> Dict[str, object]:
-    """The registry's ovl_* keys plus align_phase_seconds as a
-    JSON-ready dict (bench extras metric_version 7 / obs_report).
-    Empty when no overlap alignment ran."""
+    """The registry's ovl_* keys as a JSON-ready dict (bench extras
+    metric_version 7 / obs_report). Empty when no overlap alignment
+    ran."""
     reg = reg if reg is not None else _REGISTRY
     out: Dict[str, object] = {}
     for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("ovl_") or k == "align_phase_seconds":
+        if k.startswith("ovl_"):
             out[k] = round(v, 4) if isinstance(v, float) else v
     return out
+
+
+@contextlib.contextmanager
+def timed_span(kind: str, name: str, **attrs):
+    """A tracer span that also times itself into the registry.
+
+    Opens ``get_tracer().span(kind, name, **attrs)`` (a no-op with
+    tracing off, a profiler annotation under ``RACON_TPU_TRACE_XPROF``)
+    and, on exit, adds its wall seconds to ``<kind>_seconds_<name>``
+    whether tracing is on or not. Yields the span, so a caller can
+    ``.add()`` attrs it learns inside."""
+    t0 = time.perf_counter()
+    with _trace.get_tracer().span(kind, name, **attrs) as sp:
+        try:
+            yield sp
+        finally:
+            _REGISTRY.inc(f"{kind}_seconds_{name}",
+                          time.perf_counter() - t0)
 
 
 # --------------------------------------------------- wide-band redo
@@ -1111,7 +1121,10 @@ METRIC_SPECS = (
     ("adaptive_early_exits", MERGE_SUM, "adaptive_early_exits"),
     ("adaptive_rounds_executed", MERGE_SUM, "adaptive_rounds_executed"),
     ("adaptive_rounds_scheduled", MERGE_SUM, "adaptive_rounds_scheduled"),
-    ("align_phase_seconds", MERGE_SUM, "align_phase_seconds"),
+    ("align_bases_total", MERGE_SUM, "align_bases_total"),
+    ("align_native_bases", MERGE_SUM, "align_native_bases"),
+    ("align_seconds_*", MERGE_SUM, "align_seconds_"),
+    ("align_walk_overlaps", MERGE_SUM, "align_walk_overlaps"),
     ("ava_buckets", MERGE_LAST, "ava_buckets"),
     ("ava_compile_budget", MERGE_LAST, "ava_compile_budget"),
     ("ava_manifest_bytes_per_target", MERGE_LAST,

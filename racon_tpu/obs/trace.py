@@ -7,7 +7,9 @@ Enable by pointing ``RACON_TPU_TRACE`` at a file path, or pass
 
 Trace format (one JSON object per line):
 
-- ``{"ev": "begin", "schema": 1, "unix_time": ...}`` — first line.
+- ``{"ev": "begin", "schema": 1, "unix_time": ..., "unix_ns": ...}`` —
+  first line; ``unix_ns`` is the epoch time of ``t0 = 0``, so
+  ``unix_ns + t0 * 1e9`` puts a span on the JAX profiler's clock.
 - ``{"ev": "span", "id": N, "parent": M|null, "kind": ..., "name": ...,
   "t0": seconds-since-begin, "dur_s": ..., ...attrs}`` — one line per
   *closed* span; children therefore appear before their parent. ``kind``
@@ -227,7 +229,13 @@ class Tracer:
 
     def __init__(self, path: str):
         self.path = path
-        self._t0 = time.perf_counter()
+        # The origin on both clocks: the tightest of three back-to-back
+        # (perf_counter, epoch ns) pairs, so ``unix_ns + t0 * 1e9``
+        # puts any span on the profiler's epoch clock.
+        _, self._t0, unix_ns = min(
+            (b - a, (a + b) / 2, u) for a, u, b in
+            ((time.perf_counter(), time.time_ns(), time.perf_counter())
+             for _ in range(3)))
         self._lock = threading.Lock()
         self._local = threading.local()
         # Ids start at 1: a TraceContext's parent_id of 0 means "no
@@ -245,7 +253,7 @@ class Tracer:
         self._fh = open(self._part, "w",  # lint: atomic-ok (streamed sidecar; finish() promotes via atomic_finalize)
                         encoding="utf-8")
         self._write({"ev": "begin", "schema": SCHEMA_VERSION,
-                     "unix_time": time.time()})
+                     "unix_time": unix_ns / 1e9, "unix_ns": unix_ns})
 
     # ------------------------------------------------------------- internals
 

@@ -354,40 +354,20 @@ def _tiled_chunk_breaking_points(q, t, lq, lt, t_begin, *, match, mismatch,
     return first_c, qi_f, last_c, qi_l, valid, fail, klos
 
 
-def device_breaking_points(pending, sequences, window_length: int, *,
-                           match: int, mismatch: int, gap: int,
-                           log=None) -> List:
-    """Compute breaking points on device for as many overlaps as the
-    budget admits; returns the overlaps that still need the native path
-    (too long, escape-bound failure, or walk saturation).
-
-    Sets ``o.breaking_points`` (int64[N, 4], reference row format) on
-    every handled overlap — ``find_breaking_points`` then no-ops.
-    """
-    import jax
-    from racon_tpu.obs import metrics as obs_metrics
-    from racon_tpu.obs import trace as _trace
+def _classify(pending, sequences):
+    """Split ``pending`` into untiled device jobs ``(overlap, q_codes,
+    t_codes, q_start)``, tiled jobs (the same plus their tile plan) and
+    overlaps no device tier admits; returns those three lists."""
     from racon_tpu.ops.encode import encode_bases
 
-    tracer = _trace.get_tracer()
     tiled_on = envspec.read("RACON_TPU_OVL_TILED") != "0"
     hbm = device_hbm_bytes() if tiled_on else 0
-    jobs = []        # (overlap, q_codes, t_codes, q_start)
-    tiled_jobs = []  # (overlap, q_codes, t_codes, q_start, plan)
-    fallback = []
-    # The two fallback causes are counted INDEPENDENTLY, at the point
-    # each is known: n_budget here at classification, n_uncert at
-    # collect. The old `len(pending) - len(jobs)` subtraction lumped
-    # uncertified lanes in with over-budget ones whenever both occurred
-    # in one batch.
-    n_budget = 0
-    n_uncert = 0
+    jobs, tiled_jobs, over_budget = [], [], []
     for o in pending:
         qb, tb = o.alignment_operands(sequences)
         lq, lt = len(qb), len(tb)
         if lq < 1 or lt < 1:
-            fallback.append(o)
-            n_budget += 1
+            over_budget.append(o)
             continue
         q_start = o.q_begin if not o.strand else o.q_length - o.q_end
         W = _round_up(band_width_for_read(lq, lt), 512)
@@ -403,29 +383,21 @@ def device_breaking_points(pending, sequences, window_length: int, *,
             tiled_jobs.append((o, encode_bases(bytes(qb)),
                                encode_bases(bytes(tb)), q_start, plan))
         else:
-            fallback.append(o)
-            n_budget += 1
-    if not jobs and not tiled_jobs:
-        # A fully-rejected set must still say so — this exact condition
-        # once hid the genome workload falling back wholesale.
-        if log is not None and fallback:
-            print(f"[racon_tpu::Polisher::initialize] all {len(pending)} "
-                  "overlap alignments exceed the device length budget; "
-                  "using the native path", file=log)
-        obs_metrics.record_ovl(device_jobs=0, native_jobs=len(fallback),
-                               tiles=0)
-        return fallback
+            over_budget.append(o)
+    return jobs, tiled_jobs, over_budget
 
-    pallas = jax.default_backend() == "tpu"
-    # RUN-level shape buckets: every distinct (Lq, LA, W) triple is a
-    # fresh executable costing seconds to tens of seconds of compile —
-    # per-chunk shape maxima once turned the 2 Mb genome run's alignment
-    # phase into compile churn. Jobs sort by length
-    # and buckets grow greedily under the running-maxima budget (padded
-    # Lq from one job combined with the band width of another can
-    # overflow the int32 flat-index budget even when each job fits
-    # alone), so a uniform read set compiles exactly once; each bucket
-    # then executes in TB-lane chunks.
+
+def _bucket(jobs):
+    """RUN-level shape buckets ``(jobs, Lq, LA, W)`` of the untiled jobs.
+
+    Every distinct (Lq, LA, W) triple is a fresh executable costing
+    seconds to tens of seconds of compile — per-chunk shape maxima once
+    turned the 2 Mb genome run's alignment phase into compile churn.
+    Jobs sort by length and buckets grow greedily under the
+    running-maxima budget (padded Lq from one job combined with the
+    band width of another can overflow the int32 flat-index budget
+    even when each job fits alone), so a uniform read set compiles
+    exactly once; each bucket then executes in TB-lane chunks."""
     jobs.sort(key=lambda j: (len(j[1]), len(j[2])))
     buckets = []
     cur: List = []
@@ -446,14 +418,19 @@ def device_breaking_points(pending, sequences, window_length: int, *,
         cur.append(j)
     if cur:
         buckets.append((cur, Lq, LA, W))
+    return buckets
 
-    # Tiled jobs bucket per tier (lanes, W, T, ch): every member passed
-    # tile_plan's element gate at ITS OWN padded Lq, and the bucket's
-    # running maxima only ever equal some member's padding, so one
-    # bucket per tier never overflows the cap. LA additionally rides up
-    # to Lq — the per-tile tband slice into the padded target table
-    # indexes lane*LA + klo + i0 + y and needs LA >= Lq to stay inside
-    # the neighbouring-lane slack (_tiled_chunk_breaking_points).
+
+def _bucket_tiled(tiled_jobs):
+    """One bucket ``(jobs, lanes, W, T, ch, Lq, LA, nxt_k)`` per tier.
+
+    Every member passed tile_plan's element gate at ITS OWN padded Lq,
+    and the bucket's running maxima only ever equal some member's
+    padding, so one bucket per tier never overflows the cap. LA
+    additionally rides up to Lq — the per-tile tband slice into the
+    padded target table indexes lane*LA + klo + i0 + y and needs
+    LA >= Lq to stay inside the neighbouring-lane slack
+    (_tiled_chunk_breaking_points)."""
     tiled_buckets = []
     bytier = {}
     for j in tiled_jobs:
@@ -463,16 +440,39 @@ def device_breaking_points(pending, sequences, window_length: int, *,
         Lq_t = max(_round_up(len(j[1]), T_t) for j in js)
         LA_t = max(Lq_t, max(_round_up(len(j[2]), 2048) for j in js))
         tiled_buckets.append((js, lanes, W_t, T_t, ch_t, Lq_t, LA_t, k_t))
+    return tiled_buckets
 
-    # Dispatch every chunk before collecting any: jit calls are async,
-    # so chunk i+1's h2d overlaps chunk i's compute (h2d otherwise
-    # serializes with device time).
-    import sys as _sys
-    import time as _time
-    verbose = envspec.read("RACON_TPU_TIMING") not in ("", "0")
-    t_disp = _time.perf_counter()
-    pending_out = []
+
+def _pack(sub, B, Lq, LA):
+    """The chunk's host operands: ``sub``'s codes padded to B lanes."""
+    q = np.zeros((B, Lq), np.uint8)
+    t = np.zeros((B, LA), np.uint8)
+    lq = np.ones(B, np.int32)
+    lt = np.ones(B, np.int32)
+    t_begin = np.zeros(B, np.int32)
+    for b, job in enumerate(sub):
+        o, qc, tc = job[:3]
+        q[b, :len(qc)] = qc
+        t[b, :len(tc)] = tc
+        lq[b] = len(qc)
+        lt[b] = len(tc)
+        t_begin[b] = o.t_begin
+    return q, t, lq, lt, t_begin
+
+
+def _dispatch(buckets, tiled_buckets, window_length, *, match, mismatch,
+              gap):
+    """Dispatch every chunk before collecting any: jit calls are async,
+    so chunk i+1's h2d overlaps chunk i's compute (h2d otherwise
+    serializes with device time). Returns ``[(jobs, outputs), ...]``
+    and the number of query-axis tiles the tiled chunks execute."""
+    import jax
+    from racon_tpu.obs import trace as _trace
     from racon_tpu.ops.budget import walk_k_for
+
+    tracer = _trace.get_tracer()
+    pallas = jax.default_backend() == "tpu"
+    pending_out = []
     for bucket, Lq, LA, W in buckets:
         # Per-bucket walk depth: the u16 nxt2 plane must fit the element
         # cap at the BUCKET's padded geometry (walk_k_for degrades the
@@ -485,17 +485,7 @@ def device_breaking_points(pending, sequences, window_length: int, *,
         B = TB
         for s in range(0, len(bucket), B):
             sub = bucket[s:s + B]
-            q = np.zeros((B, Lq), np.uint8)
-            t = np.zeros((B, LA), np.uint8)
-            lq = np.ones(B, np.int32)
-            lt = np.ones(B, np.int32)
-            t_begin = np.zeros(B, np.int32)
-            for b, (o, qc, tc, _) in enumerate(sub):
-                q[b, :len(qc)] = qc
-                t[b, :len(tc)] = tc
-                lq[b] = len(qc)
-                lt[b] = len(tc)
-                t_begin[b] = o.t_begin
+            q, t, lq, lt, t_begin = _pack(sub, B, Lq, LA)
             with tracer.span("dispatch", "ovl_chunk", lanes=B, W=W):
                 pending_out.append((sub, _chunk_breaking_points(
                     q, t, lq, lt, t_begin, match=match, mismatch=mismatch,
@@ -514,34 +504,23 @@ def device_breaking_points(pending, sequences, window_length: int, *,
             B = lanes
             while B // 2 >= max(8, len(sub)):
                 B //= 2
-            q = np.zeros((B, Lq), np.uint8)
-            t = np.zeros((B, LA), np.uint8)
-            lq = np.ones(B, np.int32)
-            lt = np.ones(B, np.int32)
-            t_begin = np.zeros(B, np.int32)
-            for b, (o, qc, tc, _, _) in enumerate(sub):
-                q[b, :len(qc)] = qc
-                t[b, :len(tc)] = tc
-                lq[b] = len(qc)
-                lt[b] = len(tc)
-                t_begin[b] = o.t_begin
+            q, t, lq, lt, t_begin = _pack(sub, B, Lq, LA)
             with tracer.span("dispatch", "ovl_tiled_chunk", lanes=B,
                              W=W, tiles=n_tiles):
-                for ti in range(n_tiles):
-                    tracer.point("tile", f"t{ti}", index=ti, rows=T, W=W)
                 pending_out.append((sub, _tiled_chunk_breaking_points(
                     q, t, lq, lt, t_begin, match=match, mismatch=mismatch,
                     gap=gap, W=W, w_len=window_length, NW=NW, Lq=Lq,
                     LA=LA, T=T, tb=B, ch=ch, pallas=pallas,
                     nxt_k=nxt_k)))
             n_tiles_exec += n_tiles
+    return pending_out, n_tiles_exec
 
-    if verbose:
-        print(f"[racon_tpu::ovl_align] dispatch {len(pending_out)} "
-              f"chunks ({len(buckets)} shape buckets, "
-              f"{len(tiled_buckets)} tiled tiers): "
-              f"{_time.perf_counter() - t_disp:.2f}s", file=_sys.stderr)
-        t_disp = _time.perf_counter()
+
+def _collect(pending_out, fallback) -> int:
+    """Pull each chunk's results (blocking until the device has them),
+    set the breaking points of every certified lane's overlap and append
+    the rest to ``fallback``; returns how many were uncertified."""
+    n_uncert = 0
     for sub, out in pending_out:
         # Untiled chunks return 6 fields; tiled chunks append a klos
         # observability field that the collect path does not consume.
@@ -560,11 +539,54 @@ def device_breaking_points(pending, sequences, window_length: int, *,
                 q_start + qi_l[b][v] + 1,
             ], axis=1).astype(np.int64)
             o.breaking_points = rows
-    if verbose:
-        print(f"[racon_tpu::ovl_align] collect: "
-              f"{_time.perf_counter() - t_disp:.2f}s", file=_sys.stderr)
+    return n_uncert
+
+
+def device_breaking_points(pending, sequences, window_length: int, *,
+                           match: int, mismatch: int, gap: int,
+                           log=None) -> List:
+    """Compute breaking points on device for as many overlaps as the
+    budget admits; returns the overlaps that still need the native path
+    (too long, escape-bound failure, or walk saturation).
+
+    Sets ``o.breaking_points`` (int64[N, 4], reference row format) on
+    every handled overlap — ``find_breaking_points`` then no-ops.
+    Its three host stretches are the ``align`` spans ``device_prep``,
+    ``device_dispatch`` and ``device_collect`` (obs/metrics.py
+    ``timed_span``).
+    """
+    from racon_tpu.obs import metrics as obs_metrics
+
+    with obs_metrics.timed_span("align", "device_prep",
+                                overlaps=len(pending)):
+        jobs, tiled_jobs, fallback = _classify(pending, sequences)
+        buckets = _bucket(jobs)
+        tiled_buckets = _bucket_tiled(tiled_jobs)
+    # The two fallback causes are counted INDEPENDENTLY, at the point
+    # each is known: n_budget at classification, n_uncert at collect.
+    n_budget = len(fallback)
+    n_device = len(jobs) + len(tiled_jobs)
+    if not n_device:
+        # A fully-rejected set must still say so — this exact condition
+        # once hid the genome workload falling back wholesale.
+        if log is not None and fallback:
+            print(f"[racon_tpu::Polisher::initialize] all {len(pending)} "
+                  "overlap alignments exceed the device length budget; "
+                  "using the native path", file=log)
+        obs_metrics.record_ovl(device_jobs=0, native_jobs=len(fallback),
+                               tiles=0)
+        return fallback
+
+    with obs_metrics.timed_span("align", "device_dispatch",
+                                overlaps=n_device):
+        pending_out, n_tiles_exec = _dispatch(
+            buckets, tiled_buckets, window_length, match=match,
+            mismatch=mismatch, gap=gap)
+    with obs_metrics.timed_span("align", "device_collect",
+                                overlaps=n_device):
+        n_uncert = _collect(pending_out, fallback)
     obs_metrics.record_ovl(
-        device_jobs=len(jobs) + len(tiled_jobs) - n_uncert,
+        device_jobs=n_device - n_uncert,
         native_jobs=len(fallback), tiles=n_tiles_exec)
     if log is not None and fallback:
         print(f"[racon_tpu::Polisher::initialize] {len(fallback)} of "

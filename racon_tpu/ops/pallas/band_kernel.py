@@ -289,6 +289,8 @@ def fw_dirs_band(tband: jnp.ndarray, qT: jnp.ndarray, klo: jnp.ndarray,
     out_shape.append(jax.ShapeDtypeStruct((W, B), dtype))
     outs = pl.pallas_call(
         kernel,
+        # Profiler traces name the kernel by this: keep it stable.
+        name="fw_dirs_band",
         grid=(B // tb, Lq // ch),
         in_specs=[
             pl.BlockSpec((W + Lq, tb), lambda b, c: (0, b),
@@ -589,6 +591,8 @@ def fw_dirs_band_tile(tband: jnp.ndarray, qT: jnp.ndarray,
                   jax.ShapeDtypeStruct((W, B), jnp.int32)]
     outs = pl.pallas_call(
         kernel,
+        # Profiler traces name the kernel by this: keep it stable.
+        name="fw_dirs_band_tile",
         grid=(B // tb, T // ch),
         in_specs=[
             pl.BlockSpec((W + T, tb), lambda b, c: (0, b),

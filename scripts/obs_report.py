@@ -43,9 +43,10 @@ KIND_REQUIRED_ATTRS = {
     "retry": ("attempt", "error"),
     "fault": ("index", "action"),
     "checkpoint": ("tid", "bytes"),
-    # One query-axis tile of the tiled ultralong overlap forward,
-    # emitted under the ovl_tiled_chunk dispatch span (ops/ovl_align.py).
-    "tile": ("index", "rows", "W"),
+    # One host stretch of the overlap-alignment phase (models/
+    # polisher.py, ops/ovl_align.py, via obs/metrics.py timed_span):
+    # revcomp, device_prep/_dispatch/_collect, native_redo, bp_walk.
+    "align": ("overlaps",),
     # One distributed-ledger event (claim/steal/renew/commit/merge,
     # racon_tpu/distributed/): which shard, and which worker did it.
     "dist": ("shard", "worker"),
@@ -230,7 +231,7 @@ def render(tr: Dict[str, object], out=None,
         by_kind.setdefault(s["kind"], []).append(s)
 
     for kind in ("phase", "pipeline", "stage", "chunk", "round",
-                 "dispatch", "tile"):
+                 "align", "dispatch"):
         rows = by_kind.get(kind)
         if not rows:
             continue

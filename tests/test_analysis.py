@@ -136,6 +136,8 @@ def test_span_schema_fires():
     found = list(_rule("span-schema").check(
         _fixture_ctx("span_violation.py")))
     assert {"SPAN001", "SPAN002"} <= _ids(found)
+    # Both SPAN002 emits: tracer.span and timed_span.
+    assert len({f.line for f in found if f.rule == "SPAN002"}) == 2
 
     # Validator direction: a schema kind nobody emits.
     ctx = Context(REPO, files=[], full=True,

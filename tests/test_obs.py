@@ -64,6 +64,75 @@ def test_tracer_emit_retro_span(tmp_path, tracer_sandbox):
     assert span["t0"] >= 0
 
 
+def test_tracer_clock_bridges_to_profiler(tmp_path, monkeypatch,
+                                          tracer_sandbox):
+    """``unix_ns + t0 * 1e9`` is where the JAX profiler's host plane
+    puts each span the tracer bridged into it."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+    monkeypatch.setenv(obs_trace.ENV_XPROF, "1")
+    p = tmp_path / "t.jsonl"
+    tr = obs_trace.configure(str(p))
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        for i in range(3):
+            with tr.span("align", f"clock{i}", overlaps=i):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    tr.finish()
+
+    recs = _read_trace(p)
+    unix_ns = recs[0]["unix_ns"]
+    assert recs[0]["unix_time"] == pytest.approx(unix_ns / 1e9)
+    (xplane,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile" /
+                              "*" / "*.xplane.pb"))
+    profile = ProfileData.from_file(xplane)
+    planes = list(profile.planes)
+    # On the CPU the host plane counts from the session's start, which
+    # the "Task Environment" plane gives on the epoch clock (a TPU
+    # host's events are on the epoch clock already).
+    (origin,) = [v for plane in planes for k, v in plane.stats
+                 if k == "profile_start_time"]
+    starts = {e.name: origin + e.start_ns for plane in planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for e in line.events}
+    spans = [r for r in recs if r["ev"] == "span"]
+    assert len(spans) == 3
+    for s in spans:
+        bridged = starts[f"{s['kind']}:{s['name']}"]
+        assert abs(bridged - (unix_ns + s["t0"] * 1e9)) < 1e6
+
+
+def test_timed_span_times_into_registry(tmp_path, monkeypatch,
+                                        tracer_sandbox):
+    """timed_span adds its seconds to ``<kind>_seconds_<name>`` with
+    tracing off (no span anywhere) and on (a span with its attrs)."""
+    monkeypatch.delenv(obs_trace.ENV_TRACE, raising=False)
+    obs_trace._tracer = None
+    obs_metrics.reset()
+    with obs_metrics.timed_span("align", "probe", overlaps=1) as sp:
+        sp.add(walked=2)
+    off = obs_metrics.registry().get("align_seconds_probe")
+    assert off >= 0
+    assert not isinstance(obs_trace.get_tracer(), obs_trace.Tracer)
+
+    p = tmp_path / "t.jsonl"
+    obs_trace.configure(str(p))
+    with obs_metrics.timed_span("align", "probe", overlaps=3) as sp:
+        sp.add(walked=4)
+    obs_trace.get_tracer().finish()
+    on = obs_metrics.registry().get("align_seconds_probe") - off
+    (span,) = [r for r in _read_trace(p) if r["ev"] == "span"]
+    assert (span["kind"], span["name"]) == ("align", "probe")
+    assert span["overlaps"] == 3 and span["walked"] == 4
+    assert on == pytest.approx(span["dur_s"], abs=1e-3)
+    obs_metrics.reset()
+
+
 def test_configure_env_and_idempotence(tmp_path, monkeypatch,
                                        tracer_sandbox):
     p = tmp_path / "env.jsonl"

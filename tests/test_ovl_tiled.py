@@ -22,6 +22,7 @@ These tests pin, bottom-up:
   * the RACON_TPU_OVL_TILED env gate.
 """
 
+import gzip
 import io
 
 import numpy as np
@@ -37,6 +38,7 @@ from racon_tpu.ops.ovl_align import (_chunk_breaking_points,
 from racon_tpu.ops.pallas.band_kernel import (UC_BOUNDARY, band_geometry,
                                               fw_dirs_band_xla,
                                               fw_dirs_band_xla_tile)
+from racon_tpu.utils.logger import Logger
 
 from test_ovl_align import _layer_snapshot, _write_dataset
 
@@ -235,7 +237,8 @@ def test_ultralong_device_matches_native(tmp_path, read_len, rate):
     pn = create_polisher(*args, backend="native")
     pn.initialize()
     obs_metrics.reset()
-    pj = create_polisher(*args, backend="jax")
+    pj = create_polisher(*args, backend="jax",
+                         logger=Logger(stream=io.StringIO()))
     pj.initialize()
     assert _layer_snapshot(pj) == _layer_snapshot(pn)
     reg = obs_metrics.registry()
@@ -243,7 +246,7 @@ def test_ultralong_device_matches_native(tmp_path, read_len, rate):
     assert reg.get("ovl_device_jobs") == 4
     assert reg.get("ovl_tiles_exec") >= 2
     assert reg.get("ovl_device_fraction") == 1.0
-    assert float(reg.get("align_phase_seconds")) > 0
+    assert float(reg.get("phase_seconds_initialize_aligned_overlaps")) > 0
 
 
 @pytest.mark.parametrize("read_len,rate", [(24_000, 0.025),
@@ -317,10 +320,12 @@ def test_fallback_accounting_counts_causes_independently():
     assert reg.get("ovl_device_fraction") == 0.0
 
 
-def test_tiled_env_gate_off_routes_native(monkeypatch):
+def test_tiled_env_gate_off_routes_native(tmp_path, monkeypatch):
     """RACON_TPU_OVL_TILED=0 disables the tiled path: an ultralong job
     that WOULD plan (tile_plan admits it) must fall back as over-budget
-    without dispatching any device work."""
+    without dispatching any device work. In a polisher job the native
+    redo then counts its query + target bases in
+    ``align_native_bases``, and no device span opens."""
     assert budget.tile_plan(10_000, 10_000) is not None
     monkeypatch.setenv("RACON_TPU_OVL_TILED", "0")
     rng = np.random.default_rng(8)
@@ -335,3 +340,20 @@ def test_tiled_env_gate_off_routes_native(monkeypatch):
     assert reg.get("ovl_native_jobs") == 1
     assert reg.get("ovl_device_jobs") == 0
     assert reg.get("ovl_tiles_exec") == 0
+
+    d = _write_dataset(tmp_path, n_reads=1, read_len=10_000, seed=8,
+                       rate=0.03, draft_len=14_000)
+    obs_metrics.reset()
+    p = create_polisher(f"{d}/reads.fasta.gz", f"{d}/overlaps.paf.gz",
+                        f"{d}/draft.fasta.gz", PolisherType.kC, 500, 10.0,
+                        0.3, 5, -4, -8, backend="jax")
+    p.initialize()
+    reg = obs_metrics.registry().snapshot()
+    assert reg["ovl_native_jobs"] == 1 and reg["ovl_device_jobs"] == 0
+    with gzip.open(f"{d}/reads.fasta.gz", "rb") as f:
+        read = f.read().split(b"\n")[1]
+    assert reg["align_native_bases"] == len(read) + 10_000
+    assert reg["align_bases_total"] == reg["align_native_bases"]
+    assert reg["align_walk_overlaps"] == 1
+    assert reg["align_seconds_native_redo"] > 0
+    assert "align_seconds_device_dispatch" not in reg
