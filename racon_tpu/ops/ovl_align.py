@@ -189,8 +189,8 @@ def _tiled_chunk_breaking_points(q, t, lq, lt, t_begin, *, match, mismatch,
     2. runs fw_dirs_band_tile / its XLA twin with the carried frontier
        (H row i0, packed (N,U,C) metadata of row i0, running hlast),
     3. updates the running band clearance ``cmin`` — the certificate
-       below needs the MINIMUM distance from any tile's band edges to
-       the legal-origin interval, and
+       below needs the MINIMUM distance from any LIVE tile's band edges
+       (``i0 < lq``) to the legal-origin interval, and
     4. re-centers klo on the frontier argmax with a W/4..3W/4 dead zone
        (no-drift reads keep klo fixed and are bit-identical to the
        untiled straight band) clamped to [max(0,d)-W+1, min(0,d)] — the
@@ -218,7 +218,14 @@ def _tiled_chunk_breaking_points(q, t, lq, lt, t_begin, *, match, mismatch,
                  + gap*(|lt - lq| + 2*cmin + 2)
 
     certifies banded == global. With the dead zone inactive cmin == wl
-    and this is exactly the untiled bound.
+    and this is exactly the untiled bound. Only live tiles (``i0 <
+    lq``) enter cmin: an escaping path from (0, 0) to (lq, lt) crosses
+    a band edge at some row r <= lq, and tile i0 holds rows i0+1 ..
+    i0+T, so r lies in a tile with i0 < r <= lq. Tiles wholly past lq
+    hold no row of any such path; a lane shorter than its bucket's Lq
+    still runs them, and their re-centering on a dead frontier may
+    drive klo into the clamp (harmless: the clamp keeps hlF's terminal
+    cell in range), but their clearance bounds nothing.
 
     Returns the same tuple contract as _chunk_breaking_points.
     """
@@ -257,8 +264,12 @@ def _tiled_chunk_breaking_points(q, t, lq, lt, t_begin, *, match, mismatch,
 
     def tile_body(carry, i0):
         prev, uc, hl, klo, cmin = carry
-        cmin = jnp.minimum(
-            cmin, jnp.minimum(klo_hi - klo, klo - klo_lo))
+        # Only tiles holding rows of the lane's alignment (rows i0+1..)
+        # count: past lq the anchor chases a dead frontier into the clamp.
+        cmin = jnp.where(
+            i0 < lq,
+            jnp.minimum(cmin, jnp.minimum(klo_hi - klo, klo - klo_lo)),
+            cmin)
         # This tile's pre-shifted target window at the current origin:
         # tband[b, y] = t[b, klo_b + i0 + y] (bucketing guarantees
         # LA >= Lq, so the padded-table slice stays in range).

@@ -20,8 +20,14 @@ def _write_dataset(tmp_path, n_reads=24, read_len=2400, seed=5,
                    rate=0.12, draft_len=40_000):
     """Tiny synthetic draft + reads + PAF with ``rate`` read-vs-draft
     error (default ~12%, ONT-class; the tiled ultralong tests use lower
-    rates with longer reads — see test_ovl_tiled.py)."""
+    rates with longer reads — see test_ovl_tiled.py). ``read_len`` is
+    one draft span for every read, or a sequence of one span per read
+    (``n_reads`` is then its length)."""
     rng = np.random.default_rng(seed)
+    if np.ndim(read_len):
+        spans = [int(n) for n in read_len]
+    else:
+        spans = [read_len] * n_reads
     bases = np.frombuffer(b"ACGT", np.uint8)
     draft = bases[rng.integers(0, 4, draft_len)]
 
@@ -43,7 +49,7 @@ def _write_dataset(tmp_path, n_reads=24, read_len=2400, seed=5,
     rc[bases] = np.frombuffer(b"TGCA", np.uint8)
 
     reads, paf = [], []
-    for i in range(n_reads):
+    for i, read_len in enumerate(spans):
         t0 = int(rng.integers(0, len(draft) - read_len))
         seg = mutate(draft[t0:t0 + read_len], rate)
         strand = i % 3 == 1

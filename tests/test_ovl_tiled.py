@@ -15,9 +15,13 @@ These tests pin, bottom-up:
   * chunk-level bit-identity vs the untiled chunk (single tile and
     multi-tile, no drift),
   * anchor re-centering through a controlled diagonal excursion,
+  * lanes shorter than the chunk's Lq: the tiles past a lane's last row
+    stay out of its certificate (every lane certifies, as it does alone
+    at its own length), and an excursion past the band in the rows it
+    does hold still fails it,
   * polisher-level device-vs-native layer equality on reads past the
-    ~9 kb untiled ceiling, with the registry confirming zero native
-    fallbacks,
+    ~9 kb untiled ceiling, of one length and of mixed lengths in one
+    tier, with the registry confirming zero native fallbacks,
   * the independent over-budget / uncertified fallback accounting,
   * the RACON_TPU_OVL_TILED env gate.
 """
@@ -62,36 +66,49 @@ def _mutate_codes(rng, tgt, err):
     return np.array(out, np.uint8)
 
 
-def _mk_chunk(rng, read_len, err, B, Lq, LA):
-    """B lanes of mutated (query, target) code pairs padded to the
-    chunk geometry, mirroring the dispatcher's packing."""
+def _pack_lanes(pairs, B, Lq, LA):
+    """(query, target) code pairs in lanes 0.. of a B-lane chunk padded
+    to the chunk geometry, the rest padding lanes, mirroring the
+    dispatcher's packing."""
     q = np.zeros((B, Lq), np.uint8)
     t = np.zeros((B, LA), np.uint8)
     lq = np.ones(B, np.int32)
     lt = np.ones(B, np.int32)
-    t_begin = np.zeros(B, np.int32)
+    for b, (qq, tt) in enumerate(pairs):
+        q[b, :len(qq)] = qq
+        t[b, :len(tt)] = tt
+        lq[b] = len(qq)
+        lt[b] = len(tt)
+    return q, t, lq, lt, np.zeros(B, np.int32)
+
+
+def _mk_chunk(rng, read_len, err, B, Lq, LA):
+    """B lanes of mutated (query, target) code pairs at random target
+    offsets."""
+    pairs, t_begin = [], np.zeros(B, np.int32)
     for b in range(B):
         tgt = rng.integers(0, 4, read_len).astype(np.uint8)
-        qq = _mutate_codes(rng, tgt, err)
-        q[b, :len(qq)] = qq
-        t[b, :len(tgt)] = tgt
-        lq[b] = len(qq)
-        lt[b] = len(tgt)
+        pairs.append((_mutate_codes(rng, tgt, err), tgt))
         t_begin[b] = int(rng.integers(0, 700))
-    return q, t, lq, lt, t_begin
+    return _pack_lanes(pairs, B, Lq, LA)[:4] + (t_begin,)
 
 
-def _run_both(q, t, lq, lt, t_begin, *, W, T, Lq, LA,
-              scoring=(0, -1, -1)):
-    m, x, g = scoring
-    kw = dict(match=m, mismatch=x, gap=g, W=W, w_len=500,
-              NW=LA // 500 + 2, Lq=Lq, LA=LA)
-    out_u = [np.asarray(a) for a in _chunk_breaking_points(
-        q, t, lq, lt, t_begin, pallas=False, **kw)]
-    out_t = [np.asarray(a) for a in _tiled_chunk_breaking_points(
+def _chunk_kw(W, Lq, LA):
+    return dict(match=0, mismatch=-1, gap=-1, W=W, w_len=500,
+                NW=LA // 500 + 2, Lq=Lq, LA=LA)
+
+
+def _run_tiled(q, t, lq, lt, t_begin, *, W, T, Lq, LA):
+    return [np.asarray(a) for a in _tiled_chunk_breaking_points(
         q, t, lq, lt, t_begin, T=T, tb=q.shape[0], ch=8, pallas=False,
-        **kw)]
-    return out_u, out_t
+        **_chunk_kw(W, Lq, LA))]
+
+
+def _run_both(q, t, lq, lt, t_begin, *, W, T, Lq, LA):
+    out_u = [np.asarray(a) for a in _chunk_breaking_points(
+        q, t, lq, lt, t_begin, pallas=False, **_chunk_kw(W, Lq, LA))]
+    return out_u, _run_tiled(q, t, lq, lt, t_begin, W=W, T=T, Lq=Lq,
+                             LA=LA)
 
 
 def test_single_tile_chunk_bit_identity():
@@ -224,6 +241,67 @@ def test_anchor_recentering_tracks_excursion():
         assert np.array_equal(a, b), f"field {i} differs"
 
 
+def test_mixed_length_chunk_short_lanes_match_own_length():
+    """Lanes shorter than the chunk's Lq run tiles wholly past their last
+    row, where the anchor chases a dead frontier into the clamp. Those
+    tiles hold no row of the lane's alignment, so they must not count in
+    the band clearance: every lane certifies, and each short lane's
+    outputs equal that lane run alone at its own padded length."""
+    rng = np.random.default_rng(25)
+    W, T, Lq, LA, B = 512, 256, 2048, 2048, 8
+    pairs = []
+    for n in (180, 240, 300, 470, 400, 1990, 2000, 1950):
+        tgt = rng.integers(0, 4, n).astype(np.uint8)
+        pairs.append((_mutate_codes(rng, tgt, 0.07)[:Lq], tgt))
+    out = _run_tiled(*_pack_lanes(pairs, B, Lq, LA), W=W, T=T, Lq=Lq,
+                     LA=LA)
+    assert not out[5].any(), out[5]
+    for b, (qq, tt) in enumerate(pairs):
+        own = -(-len(qq) // T) * T
+        if own == Lq:
+            continue
+        alone = _run_tiled(*_pack_lanes([(qq, tt)], B, own, LA), W=W,
+                           T=T, Lq=own, LA=LA)
+        for i in range(6):
+            assert np.array_equal(out[i][b], alone[i][0]), \
+                f"lane {b} (lq {len(qq)}) field {i} differs"
+        # The live tiles ran at the same band origins.
+        assert np.array_equal(out[6][:own // T, b], alone[6][:, 0])
+        # Past the lane's last row the anchor sits in the clamp (klo_lo =
+        # max(0, lt - lq) - (W - 1)), at clearance 0: the lane certifies
+        # because those tiles stay out of cmin.
+        assert out[6][-1, b] == max(0, len(tt) - len(qq)) - (W - 1)
+
+
+def test_short_lane_excursion_past_band_stays_uncertified():
+    """Soundness: a short lane in a long chunk whose live rows hold an
+    excursion wider than the band can follow (560 deletions, then 560
+    insertions back to the main diagonal, against W = 512) must come
+    back uncertified, as it does alone at its own length. Leaving the
+    dead tiles out of the clearance must not loosen the bound for the
+    rows the lane does hold."""
+    rng = np.random.default_rng(9)
+    qq = rng.integers(0, 4, 1500).astype(np.uint8)
+    # Rows 100..1220: every other base missing from the target (the
+    # path drifts to diagonal -560); rows 1220..1500: two bases inserted
+    # after each (back to diagonal 0).
+    ins = []
+    for b in qq[1220:1500]:
+        ins += [int(b), int(rng.integers(0, 4)), int(rng.integers(0, 4))]
+    tt = np.concatenate([qq[:100], qq[100:1220:2],
+                         np.array(ins, np.uint8)])
+    assert len(tt) == len(qq)
+    good = rng.integers(0, 4, 1900).astype(np.uint8)
+    pairs = [(qq, tt), (_mutate_codes(rng, good, 0.05), good)]
+    W, T, Lq, LA, B = 512, 256, 2048, 2048, 8
+    out = _run_tiled(*_pack_lanes(pairs, B, Lq, LA), W=W, T=T, Lq=Lq,
+                     LA=LA)
+    assert out[5][0] and not out[5][1]
+    alone = _run_tiled(*_pack_lanes(pairs[:1], B, 1536, LA), W=W, T=T,
+                       Lq=1536, LA=LA)
+    assert alone[5][0]
+
+
 @pytest.mark.parametrize("read_len,rate", [(12_000, 0.03)])
 def test_ultralong_device_matches_native(tmp_path, read_len, rate):
     """Reads past the untiled ~9 kb ceiling route through the tiled
@@ -247,6 +325,28 @@ def test_ultralong_device_matches_native(tmp_path, read_len, rate):
     assert reg.get("ovl_tiles_exec") >= 2
     assert reg.get("ovl_device_fraction") == 1.0
     assert float(reg.get("phase_seconds_initialize_aligned_overlaps")) > 0
+
+
+def test_mixed_length_tiled_tier_matches_native(tmp_path):
+    """Reads of clearly different lengths in one tile tier share one
+    bucket padded to the longest: the ~9.5 kb reads run two tiles past
+    their last row. They must still certify, and every layer must equal
+    the native aligner's."""
+    d = _write_dataset(tmp_path, read_len=[9_500, 13_000, 9_500],
+                       seed=25, rate=0.03, draft_len=20_000)
+    args = (f"{d}/reads.fasta.gz", f"{d}/overlaps.paf.gz",
+            f"{d}/draft.fasta.gz", PolisherType.kC, 500, 10.0, 0.3,
+            5, -4, -8)
+    pn = create_polisher(*args, backend="native")
+    pn.initialize()
+    obs_metrics.reset()
+    pj = create_polisher(*args, backend="jax")
+    pj.initialize()
+    assert _layer_snapshot(pj) == _layer_snapshot(pn)
+    reg = obs_metrics.registry()
+    assert reg.get("ovl_native_jobs") == 0
+    assert reg.get("ovl_device_jobs") == 3
+    assert reg.get("ovl_tiles_exec") == 7
 
 
 @pytest.mark.parametrize("read_len,rate", [(24_000, 0.025),
