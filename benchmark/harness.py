@@ -20,8 +20,11 @@ A run (``run``):
 2. the window: polish the timed jobs back to back through
    ``racon_tpu.cli.main([... "--backend", "jax" ...])`` in this
    process, until ``--seconds`` have passed; the job running then is
-   finished and counted. With ``--trace 1`` the first timed job runs
-   under the JAX profiler, with the program's spans bridged into it;
+   finished and counted. With ``--trace 1`` the window is the first
+   timed job alone, run under the JAX profiler with the program's spans
+   bridged into it (collecting the profile after a job outlasts a
+   window: about 130 s on four chips); under a pool, warm-up is that
+   job polished once;
 3. after the window: read the device's peak memory, compare every timed
    job's polished contigs with their truths (``benchmark/reference.py``)
    and print the result line.
@@ -136,6 +139,14 @@ def require_chips(n: int) -> Dict:
             "count": n}
 
 
+def chip_argv(n: int) -> List[str]:
+    """The CLI's arguments that put a run on the cell's ``n`` chips: none
+    for one chip (the CLI's single-device path), else ``--dp n``, which
+    shards the consensus chunks over a ``dp`` mesh of the first ``n``
+    devices."""
+    return ["--dp", str(n)] if n > 1 else []
+
+
 def memory_peak_bytes(n: int) -> int:
     """Peak bytes in use on the fullest of the first ``n`` devices (0
     where the backend keeps no statistics)."""
@@ -170,9 +181,9 @@ class CompileCounter:
 
 # -------------------------------------------------------------------- jobs
 
-def cli_argv(cfg: Dict, paths: List[str]) -> List[str]:
-    r = cfg["racon"]
-    return ["--backend", BACKEND,
+def cli_argv(cell: Dict, paths: List[str]) -> List[str]:
+    r = cell["config"]["racon"]
+    return ["--backend", BACKEND, *chip_argv(cell["chips"]),
             "-w", str(r["window_length"]), "-q", str(r["quality_threshold"]),
             "-e", str(r["error_threshold"]), "-m", str(r["match"]),
             "-x", str(r["mismatch"]), "-g", str(r["gap"]), *paths]
@@ -220,11 +231,12 @@ def _tail(path: str, n: int = 1500) -> str:
         return ""
 
 
-def polish(cfg: Dict, job: Dict, extra: List[str] = ()) -> Dict:
+def polish(cell: Dict, job: Dict, extra: List[str] = ()) -> Dict:
     """Polish one generated job through ``racon_tpu.cli.main`` in this
-    process. Returns its wall seconds, the metrics registry's snapshot
-    for this job alone, its polished contigs (None when the CLI failed
-    or its output is malformed) and why it failed."""
+    process, on the cell's chips. Returns its wall seconds, the metrics
+    registry's snapshot for this job alone, its polished contigs (None
+    when the CLI failed or its output is malformed) and why it
+    failed."""
     from racon_tpu import cli
     from racon_tpu.obs import metrics as obs_metrics
     out_path = os.path.join(job["dir"], "polished.fasta")
@@ -234,7 +246,7 @@ def polish(cfg: Dict, job: Dict, extra: List[str] = ()) -> Dict:
     t0 = time.perf_counter()
     with _routed(out_path, log_path):
         try:
-            rc = cli.main([*extra, *cli_argv(cfg, job["paths"])])
+            rc = cli.main([*extra, *cli_argv(cell, job["paths"])])
         except Exception as exc:    # one job's fault is counted, not fatal
             rc, why = None, f"{type(exc).__name__}: {exc}"
     wall = time.perf_counter() - t0
@@ -291,10 +303,11 @@ def timed_job(cell: Dict, seed: int, i: int) -> Dict:
                     int(order[i % pool]), f"job{i}")
 
 
-def jobs_to_ready(seconds: float, warm_s: List[float]) -> int:
+def jobs_to_ready(seconds: float, pace_s: List[float]) -> int:
     """Timed jobs to have ready: a quarter more than a window of
-    ``seconds`` holds at the fastest warm-up job's pace, and one."""
-    return max(2, math.ceil(1.25 * seconds / min(warm_s)) + 1)
+    ``seconds`` holds at the pace of the fastest of ``pace_s``, and
+    one."""
+    return max(2, math.ceil(1.25 * seconds / min(pace_s)) + 1)
 
 
 # --------------------------------------------------------------------- run
@@ -366,38 +379,48 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     # The program's result caches stay off (the serial CLI's default):
     # a pooled mix's window polishes the inputs its warm-up polished.
     os.environ["RACON_TPU_CACHE"] = "0"
-    cfg = cell["config"]
     counter = CompileCounter()
 
     # The scheduler's survivor buckets depend on the bases. A pooled mix
     # warms up on the very jobs its window polishes, in the window's
-    # order, so that every program the window needs is built. Without a
-    # pool, warm-up jobs share the timed jobs' layout, so they use the
-    # same chunk shapes, and warm-up goes on until two jobs in a row
-    # need no program this process has not built yet (or MAX_WARMUP).
+    # order, so that every program the window needs is built: as many
+    # as the window holds at the pace of the warm-up jobs that built no
+    # program (a job that compiles is no measure of a window's pace).
+    # Without a pool, warm-up jobs share the timed jobs' layout, so they
+    # use the same chunk shapes, and warm-up goes on until two jobs in a
+    # row need no program this process has not built yet (or
+    # MAX_WARMUP). A traced window is one job; with a pool, warm-up
+    # polishes that very job once.
     pool = cell["traffic"].get("pool_jobs")
     warm_s: List[float] = []
+    pace_s: List[float] = []
     ready: List[Dict] = []
     clean = 0
     for k in range(pool or MAX_WARMUP):
         before = counter.counts["setup"]["compile_requests"]
         if pool:
             ready.append(timed_job(cell, seed, k))
-        w = polish(cfg, ready[k] if pool else warmup_job(cell, seed, k))
+        w = polish(cell, ready[k] if pool else warmup_job(cell, seed, k))
         if w["failed"]:
             print("benchmark: a warm-up job failed", file=sys.stderr)
             return 1
         warm_s.append(w["wall_s"])
         built = counter.counts["setup"]["compile_requests"] > before
         clean = 0 if built or k == 0 else clean + 1
-        if (k + 1 >= jobs_to_ready(seconds, warm_s)) if pool else clean == 2:
+        if clean:
+            pace_s.append(w["wall_s"])
+        if pool and (trace or pace_s and
+                     k + 1 >= jobs_to_ready(seconds, pace_s)):
             break
-    ready += [timed_job(cell, seed, i)
-              for i in range(len(ready), jobs_to_ready(seconds, warm_s))]
+        if not pool and clean == 2:
+            break
+    n_ready = 1 if trace else jobs_to_ready(seconds, pace_s or warm_s)
+    ready += [timed_job(cell, seed, i) for i in range(len(ready), n_ready)]
     setup_s = time.perf_counter() - t_setup
 
     done: List[Dict] = []
     gen_s = 0.0
+    profile_stop_s = 0.0
     counter.phase = "window"
     t0 = time.perf_counter()
     while True:
@@ -408,7 +431,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             gen_s += time.perf_counter() - tg
         job = ready[i]
         extra: List[str] = []
-        if trace and i == 0:
+        if trace:
             import jax
             extra = ["--trace", os.path.join(job["dir"], "spans.jsonl")]
             # Device and host events, the program's annotations among
@@ -418,11 +441,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             opts.python_tracer_level = 0
             opts.enable_hlo_proto = False
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        res = polish(cfg, job, extra)
-        if trace and i == 0:
+        res = polish(cell, job, extra)
+        done.append({**job, **res})
+        if trace:
+            tp = time.perf_counter()
             jax.profiler.stop_trace()
             _untrace()
-        done.append({**job, **res})
+            profile_stop_s = time.perf_counter() - tp
+            break
         if time.perf_counter() - t0 - gen_s >= seconds:
             break
     wall = time.perf_counter() - t0 - gen_s
@@ -431,7 +457,13 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                      "window": {"jobs": len(done), "wall_s": wall,
                                 "generate_s": gen_s,
                                 "job_walls_s": [j["wall_s"] for j in done],
-                                **counter.counts["window"]}}))
+                                "profile_stop_s": profile_stop_s,
+                                **counter.counts["window"]},
+                     # Real consensus lanes each chip of a dp mesh got
+                     # in the first timed job (none on one chip).
+                     "dp_shard_lanes": {
+                         k: v for k, v in done[0]["registry"].items()
+                         if k.startswith("dp_shard_lanes_")}}))
     device["memory_peak_bytes"] = memory_peak_bytes(cell["chips"])
     gc.collect()
 
@@ -448,8 +480,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     else:
         from benchmark import trace_reduce
         red = trace_reduce.reduce_dir(
-            trace_dir, os.path.join(done[0]["dir"], "spans.jsonl"))
-        ctx = {"registry": done[0]["registry"],
+            trace_dir, os.path.join(done[0]["dir"], "spans.jsonl"),
+            cell["chips"])
+        ctx = {"registry": done[0]["registry"], "chips": cell["chips"],
                "draft_mb": done[0]["draft_bp"] / 1e6, "trace": red}
         for m in cell["per_layer"]:
             v = readers[m["name"]](ctx)
@@ -458,6 +491,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         if red is not None:
             device["busy_s"] = red["busy_s"]
             device["window_s"] = red["window_s"]
+            device["busy_by_chip"] = red["busy_by_chip"]
             result["breakdown"] = {"device_ops": red["device_ops"],
                                    "idle_gaps": red["idle_gaps"]}
     result["checks"] = verdict["numbers"]

@@ -8,7 +8,8 @@ from benchmark import control, harness
 from benchmark.tests.helpers import small_root
 
 
-@pytest.mark.parametrize("cell", ["ecoli_ont.paf", "yeast_clr.sam"])
+@pytest.mark.parametrize("cell", ["ecoli_ont.paf", "yeast_clr.sam",
+                                  "ecoli_ont_dp4.paf"])
 def test_the_control_reads_not_correct(tmp_path, monkeypatch, cell):
     root = small_root(tmp_path, cell, 30_000)
     monkeypatch.setattr(harness, "WORK_DIR", root + "/.bench_work")

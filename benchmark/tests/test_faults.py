@@ -12,14 +12,15 @@ once for each fault a polishing cell can have:
 - half of the batch dropped: the last half of each consensus call's
   windows come back with no bases, so the contigs stop short;
 - an answer altered where it is produced: one window's consensus per
-  call comes back reversed.
-
-The cells run on one chip, so there is no exchange between chips to
-leave out.
+  call comes back reversed;
+- the exchange between chips left out (the four-chip cell alone, on
+  four virtual CPU devices): the consensus programs' ``psum`` over the
+  ``dp`` mesh returns each chip's own share of the votes.
 """
 
 import json
 
+import jax
 import pytest
 
 from benchmark import harness
@@ -64,7 +65,8 @@ def _altered(orig):
 
 
 # Each cell at a size the CPU polishes in a minute or two.
-CELLS = {"ecoli_ont.paf": 30_000, "yeast_clr.sam": 15_000}
+CELLS = {"ecoli_ont.paf": 30_000, "yeast_clr.sam": 15_000,
+         "ecoli_ont_dp4.paf": 30_000}
 
 
 @pytest.fixture(scope="module", params=sorted(CELLS))
@@ -98,3 +100,30 @@ def test_a_broken_timed_path_reads_not_correct(cell_root, monkeypatch,
     res = _run(*cell_root, monkeypatch)
     assert res["attempted"] >= 1
     assert res["correct"] is (fault is None), res["checks"]
+
+
+@pytest.fixture
+def no_exchange(monkeypatch):
+    """Every ``psum`` over the ``dp`` axis returns its input: each chip
+    keeps its own lanes' votes, and the replicated result is chip 0's.
+    The programs traced before (and after) are dropped, so the fault is
+    traced in and out."""
+    psum = jax.lax.psum
+
+    def own_share(x, axis_name, **kw):
+        return x if axis_name == "dp" else psum(x, axis_name, **kw)
+
+    jax.clear_caches()
+    monkeypatch.setattr(jax.lax, "psum", own_share)
+    yield
+    monkeypatch.setattr(jax.lax, "psum", psum)
+    jax.clear_caches()
+
+
+def test_leaving_out_the_exchange_between_chips_reads_not_correct(
+        tmp_path, monkeypatch, no_exchange):
+    cell = "ecoli_ont_dp4.paf"
+    assert len(jax.devices()) >= 4
+    res = _run(cell, small_root(tmp_path, cell, CELLS[cell]), monkeypatch)
+    assert res["attempted"] >= 1
+    assert res["correct"] is False, res["checks"]

@@ -7,6 +7,7 @@ import re
 import time
 
 import numpy as np
+import pytest
 
 from benchmark import harness, reference
 from benchmark.gen import genome
@@ -135,7 +136,7 @@ def test_a_pooled_run_warms_up_on_the_jobs_its_window_polishes(
                                    "count": n})
     seen = []
 
-    def polish(cfg, job, extra=()):
+    def polish(cell, job, extra=()):
         t0 = time.perf_counter()
         with open(job["paths"][2], "rb") as f:
             draft = f.read()
@@ -155,13 +156,70 @@ def test_a_pooled_run_warms_up_on_the_jobs_its_window_polishes(
     assert [n for n, _ in window] == [f"job{i}" for i in range(n_win)]
 
 
+def _compiling_first(n_building: int, seen: list):
+    """A stand-in for ``harness.polish``: the first ``n_building`` jobs
+    build programs and take 10 s by their own clock, the rest 0.5 s;
+    each answers with its draft."""
+    import jax.monitoring
+
+    def polish(cell, job, extra=()):
+        building = len(seen) < n_building
+        seen.append(os.path.basename(job["dir"]))
+        if building:
+            jax.monitoring.record_event_duration_secs(
+                "/jax/core/compile/backend_compile_duration", 1.0)
+        time.sleep(0.3)
+        with open(job["paths"][2], "rb") as f:
+            contigs = reference.parse_fasta(f.read())
+        return {"wall_s": 10.0 if building else 0.5, "registry": {},
+                "contigs": contigs, "failed": None}
+    return polish
+
+
+def _run_compiling_first(tmp_path, monkeypatch, trace):
+    root = small_root(tmp_path, "ecoli_ont.paf", 15_000)
+    monkeypatch.setattr(harness, "WORK_DIR", root + "/.bench_work")
+    monkeypatch.setattr(harness, "require_chips",
+                        lambda n: {"platform": "cpu", "kind": "cpu",
+                                   "count": n})
+    seen = []
+    monkeypatch.setattr(harness, "polish", _compiling_first(3, seen))
+    lines = []
+    assert harness.run("ecoli_ont.paf", 2**31 + 7, 1.0, trace, root=root,
+                       emit=lines.append) == 0
+    assert json.loads(lines[-1])["correct"] is False   # drafts
+    return json.loads(lines[0]), seen
+
+
+def test_a_pooled_warm_up_takes_its_pace_from_jobs_that_built_nothing(
+        tmp_path, monkeypatch):
+    # Three warm-up jobs compile; a pace taken from them (10 s) would
+    # warm up two jobs for a 1 s window, and the window's later jobs
+    # would compile inside it. At the clean jobs' 0.5 s a window holds
+    # two, so four are warmed up.
+    first, _ = _run_compiling_first(tmp_path, monkeypatch, False)
+    assert first["setup"]["warmup_s"] == [10.0, 10.0, 10.0, 0.5]
+    assert first["window"]["jobs"] >= 2
+
+
+def test_a_traced_pooled_run_warms_up_on_its_one_job(tmp_path,
+                                                     monkeypatch):
+    # A traced window is the first timed job alone; warm-up polishes
+    # that job once, however many programs it built.
+    first, seen = _run_compiling_first(tmp_path, monkeypatch, True)
+    assert first["setup"]["warmup_s"] == [10.0]
+    assert first["window"]["jobs"] == 1
+    assert seen == ["job0", "job0"]
+
+
 def test_a_small_yeast_clr_sam_job_polishes_through_the_cli(tmp_path,
                                                             monkeypatch):
     monkeypatch.setattr(harness, "BACKEND", "native")
     cfg, job = _sam_job(tmp_path, seed=11)
     names = [c["name"] for c in cfg["contigs"]]
-    res = harness.polish(cfg, {"dir": os.path.dirname(job["paths"][0]),
-                               "paths": job["paths"]})
+    res = harness.polish({"config": cfg, "chips": 1},
+                         {"dir": os.path.dirname(job["paths"][0]),
+                          "paths": job["paths"]})
     assert res["failed"] is None
     r = reference.compare_job(res["contigs"], dict(zip(names, job["truths"])))
     assert r["missing"] == []

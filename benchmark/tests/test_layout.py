@@ -67,6 +67,27 @@ def test_every_named_file_exists_and_run_py_lists_the_cells():
     spec = harness.load_spec()
     assert [c["workload"] for c in cells] == \
         [w["name"] for w in spec["workloads"]]
+    by_name = {c["workload"]: c for c in cells}
+    assert len(by_name) == 3
+    dp4 = by_name["ecoli_ont_dp4.paf"]
+    assert dp4["chips"] == 4
+    assert "device.chip_busy_imbalance" in dp4["per_layer"]
+    assert set(by_name["ecoli_ont.paf"]["per_layer"]) < set(dp4["per_layer"])
+
+
+def test_a_cell_gets_its_chips_on_the_command_line():
+    spec = harness.load_spec()
+    paths = ["reads.fastq", "overlaps.paf", "draft.fasta"]
+    argv = {w["name"]: harness.cli_argv(harness.find_cell(spec, w["name"]),
+                                        paths)
+            for w in spec["workloads"]}
+    # One chip: the CLI's single-device path, the argv as it always was.
+    one_chip = ["--backend", "jax", "-w", "500", "-q", "10.0", "-e", "0.3",
+                "-m", "5", "-x", "-4", "-g", "-8", *paths]
+    assert argv["ecoli_ont.paf"] == one_chip
+    assert argv["yeast_clr.sam"] == one_chip
+    assert argv["ecoli_ont_dp4.paf"] == \
+        ["--backend", "jax", "--dp", "4", *one_chip[2:]]
 
 
 def test_without_an_accelerator_the_run_fails_and_prints_no_result():

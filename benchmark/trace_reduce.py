@@ -6,8 +6,10 @@ one traced job. Output (``reduce_file``):
 - ``window_s``: the traced interval: the program's ``run:racon_tpu``
   span (bridged into the trace by ``RACON_TPU_TRACE_XPROF``), else the
   extent of all device and annotation events;
-- ``busy_s``: the union of the device operations' intervals inside the
-  window, averaged over the chips; ``idle_share`` is ``1 - busy/window``;
+- ``busy_by_chip``: for each chip of the cell, in device order, the
+  union of its operations' intervals inside the window; a chip that ran
+  no operation is busy 0 s. ``busy_s`` is their mean, ``idle_share``
+  ``1 - busy_s/window``;
 - ``programs``: for each jitted program (HLO module, by name), the
   device seconds of each operation name inside it, summed over chips.
   A Pallas kernel is one operation named after its kernel function
@@ -15,9 +17,10 @@ one traced job. Output (``reduce_file``):
   the programs that call it is read from here;
 - ``device_ops``: the ten ``program/operation`` names with the most
   device time, control-flow operations (which contain others) left out;
-- ``idle_gaps``: the ten host activities under which the device sat
-  idle longest: each gap between device operations is named by the
-  innermost program span open on the host across its middle
+- ``idle_gaps``: the ten host activities under which the chips sat
+  idle longest, averaged over the chips (a chip that ran nothing is
+  idle over the whole window): each gap between a chip's operations is
+  named by the innermost program span open on the host across its middle
   (``kind:name``, e.g. ``dispatch:ovl_chunk``), else by the program's
   logger phase open then (``phase:<name>``, from the job's span file,
   put on the trace's clock by the ``run`` span that both record), else
@@ -45,7 +48,7 @@ PROGRAM_NAME = re.compile(r"\(\d+\)$")
 OVERLAP_PROGRAMS = re.compile(r"breaking_points")
 # Control-flow operations contain the operations of their bodies.
 CONTAINERS = {"while", "conditional", "call"}
-DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 # Program spans carry "<kind>:<name>" (racon_tpu/obs/trace.py).
@@ -111,14 +114,20 @@ def load_phases(spans_path: str) -> Tuple[Optional[float], List]:
     return run_t0, phases
 
 
-def reduce_xspace(pd, phases=(None, [])) -> Optional[Dict]:
+def reduce_xspace(pd, phases=(None, []), chips: Optional[int] = None
+                  ) -> Optional[Dict]:
     """The reduction of one loaded ``jax.profiler.ProfileData`` (with
-    the job's logger phases from :func:`load_phases`, if any); None
-    when the trace holds no device operation."""
-    ops_by_dev: List[List[Tuple[float, float, str, str]]] = []
+    the job's logger phases from :func:`load_phases`, if any) over the
+    cell's ``chips``: devices 0 to ``chips - 1``, each counted whether
+    or not it ran an operation (None: every device plane the trace
+    holds). None when no such chip ran an operation."""
+    ops_by_dev: Dict[int, List[Tuple[float, float, str, str]]] = {}
+    planes: List[int] = []
     spans: List[Tuple[float, float, str]] = []
     for plane in pd.planes:
-        if DEVICE_PLANE.match(plane.name):
+        m = DEVICE_PLANE.match(plane.name)
+        if m:
+            planes.append(int(m.group(1)))
             lines = {ln.name: ln for ln in plane.lines}
             if OPS_LINE not in lines:
                 continue
@@ -132,20 +141,24 @@ def reduce_xspace(pd, phases=(None, [])) -> Optional[Dict]:
                     _module_of(e, modules, starts))
                    for e in lines[OPS_LINE].events if e.duration_ns > 0]
             if ops:
-                ops_by_dev.append(ops)
+                ops_by_dev[planes[-1]] = ops
         elif plane.name.startswith("/host:"):
             for ln in plane.lines:
                 for e in ln.events:
                     if SPAN_NAME.match(e.name):
                         spans.append((e.start_ns, e.start_ns + e.duration_ns,
                                       e.name))
+    if chips is None:
+        chips = max(planes, default=-1) + 1
+    ops_by_dev = {d: ops for d, ops in ops_by_dev.items() if d < chips}
     if not ops_by_dev:
         return None
     runs = [(s, e) for s, e, n in spans if n == RUN_SPAN]
     if runs:
         lo, hi = min(s for s, _ in runs), max(e for _, e in runs)
     else:
-        every = [t for ops in ops_by_dev for o in ops for t in o[:2]]
+        every = [t for ops in ops_by_dev.values() for o in ops
+                 for t in o[:2]]
         every += [t for s in spans for t in s[:2]]
         lo, hi = min(every), max(every)
     window_ns = hi - lo
@@ -158,7 +171,7 @@ def reduce_xspace(pd, phases=(None, [])) -> Optional[Dict]:
     else:
         named_phases = []
 
-    busy_ns = 0.0
+    busy_ns = [0.0] * chips
     programs: Dict[str, Dict[str, float]] = defaultdict(
         lambda: defaultdict(float))
     by_op: Dict[str, float] = defaultdict(float)
@@ -167,29 +180,32 @@ def reduce_xspace(pd, phases=(None, [])) -> Optional[Dict]:
     # else the logger phase open then.
     labels = [(s, e, n, (0, e - s)) for s, e, n in spans if n != RUN_SPAN]
     labels += [(s, e, n, (1, e - s)) for s, e, n in named_phases]
-    for ops in ops_by_dev:
-        inside = [o for o in ops if o[1] > lo and o[0] < hi]
+    for dev in range(chips):
+        inside = [o for o in ops_by_dev.get(dev, ())
+                  if o[1] > lo and o[0] < hi]
         for s, e, name, module in inside:
             d = (min(e, hi) - max(s, lo)) / 1e9
             programs[module][name] += d
             if name not in CONTAINERS:
                 by_op[f"{module}/{name}"] += d
         busy = _clip(_union([(o[0], o[1]) for o in inside]), lo, hi)
-        busy_ns += sum(e - s for s, e in busy)
+        busy_ns[dev] = sum(e - s for s, e in busy)
         edges = [lo] + [t for iv in busy for t in iv] + [hi]
         idle = [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
         for name, ns in _label_time(idle, labels).items():
             gaps[name] += ns / 1e9
-    n_dev = len(ops_by_dev)
+    mean_ns = sum(busy_ns) / chips
     top = lambda d: [[k, v] for k, v in sorted(d.items(),
                                                key=lambda kv: -kv[1])[:10]]
     return {"window_s": window_ns / 1e9,
-            "busy_s": busy_ns / n_dev / 1e9,
-            "idle_share": 1.0 - busy_ns / n_dev / window_ns,
-            "devices": n_dev,
+            "busy_s": mean_ns / 1e9,
+            "idle_share": 1.0 - mean_ns / window_ns,
+            "chips": chips,
+            "devices": len(ops_by_dev),
+            "busy_by_chip": [ns / 1e9 for ns in busy_ns],
             "programs": {m: dict(v) for m, v in programs.items()},
             "device_ops": top(by_op),
-            "idle_gaps": top({k: v / n_dev for k, v in gaps.items()})}
+            "idle_gaps": top({k: v / chips for k, v in gaps.items()})}
 
 
 def _label_time(idle: List[Interval], labels) -> Dict[str, float]:
@@ -226,19 +242,20 @@ def find_xplane(trace_dir: str) -> Optional[str]:
     return found[-1] if found else None
 
 
-def reduce_file(path: str, spans_path: Optional[str] = None
-                ) -> Optional[Dict]:
+def reduce_file(path: str, spans_path: Optional[str] = None,
+                chips: Optional[int] = None) -> Optional[Dict]:
     from jax.profiler import ProfileData
     phases = load_phases(spans_path) if spans_path else (None, [])
-    return reduce_xspace(ProfileData.from_file(path), phases)
+    return reduce_xspace(ProfileData.from_file(path), phases, chips)
 
 
-def reduce_dir(trace_dir: str, spans_path: Optional[str] = None
-               ) -> Optional[Dict]:
-    """The reduction of the newest trace under ``trace_dir``; None when
-    there is none or it holds no device operation."""
+def reduce_dir(trace_dir: str, spans_path: Optional[str] = None,
+               chips: Optional[int] = None) -> Optional[Dict]:
+    """The reduction of the newest trace under ``trace_dir`` over the
+    cell's ``chips``; None when there is none or no chip of the cell
+    ran an operation."""
     path = find_xplane(trace_dir)
-    return reduce_file(path, spans_path) if path else None
+    return reduce_file(path, spans_path, chips) if path else None
 
 
 def kernel_seconds(red: Optional[Dict], kernel: str, overlap: bool
