@@ -19,6 +19,10 @@ mix (``benchmark/traffic/<name>.json``):
   read against the draft composed through the truth coordinates, as an
   aligner would report it (``benchmark/gen/sam.py``).
 
+A mix whose overlaps are ``ava_paf`` is a read self-correction job,
+all-vs-all overlaps among the reads and no draft
+(``benchmark/gen/ava.py``).
+
 Everything is a pure function of the arguments, so a job regenerates
 byte-identically from its seed.
 """
@@ -126,12 +130,17 @@ def _rates(profile: Dict):
 def make_job(cfg: Dict, traffic: Dict, rng, out_dir: str) -> Dict:
     """Write one job into ``out_dir``: ``draft.fasta``, the reads
     (``reads.fastq`` or ``reads.fasta``) and the overlaps
-    (``overlaps.paf`` or ``overlaps.sam``).
+    (``overlaps.paf`` or ``overlaps.sam``); under ``ava_paf``, the job
+    ``ava.make_job`` writes.
 
     Returns the three paths in the order the CLI takes them, each
-    contig's truth as bytes, and the draft's length in bases."""
-    os.makedirs(out_dir, exist_ok=True)
+    contig's truth as bytes keyed by the name the program prints for
+    it (the contig's own), and the draft's length in bases."""
     fmt_ovl = traffic["overlaps"]
+    if fmt_ovl == "ava_paf":
+        from benchmark.gen import ava   # ava builds on this module
+        return ava.make_job(cfg, traffic, rng, out_dir)
+    os.makedirs(out_dir, exist_ok=True)
     fmt_reads = traffic["reads"]
     if fmt_ovl not in ("paf", "sam") or fmt_reads not in ("fastq", "fasta"):
         raise ValueError(f"unknown traffic formats {fmt_ovl!r}/{fmt_reads!r}")
@@ -140,7 +149,7 @@ def make_job(cfg: Dict, traffic: Dict, rng, out_dir: str) -> Dict:
     draft_bytes: List[bytes] = []
     reads: List[bytes] = []
     ovls: List[str] = []
-    truths: List[bytes] = []
+    truths: Dict[str, bytes] = {}
     header: List[str] = []
     draft_bp = 0
     n_read = 0
@@ -155,7 +164,7 @@ def make_job(cfg: Dict, traffic: Dict, rng, out_dir: str) -> Dict:
         truth = full[flank:flank + bp]
         draft, d_starts, d_keep, d_ins = mutate(rng, truth,
                                                 *_rates(cfg["draft_error"]))
-        truths.append(truth.tobytes())
+        truths[name] = truth.tobytes()
         draft_bp += len(draft)
         draft_bytes.append(b">" + name.encode() + b"\n" + draft.tobytes()
                            + b"\n")

@@ -2,13 +2,24 @@
 
 ``BENCHMARK.json`` at the checkout's root names the cells. A cell is a
 configuration (``benchmark/configs/<config>.json``: the deployment's
-sizes, error profiles and racon's settings) under a traffic mix
-(``benchmark/traffic/<traffic>.json``: the input formats and how jobs
-arrive); its correctness limits are ``benchmark/limits/<cell>.json``,
-and each per-layer metric is read by ``benchmark/metrics/<metric>.py``.
-The harness finds all of them by the names in ``BENCHMARK.json``, so a
-new cell, configuration, mix or metric is a new file and an entry
-there, never an edit here.
+sizes, error profiles and racon's settings, its ``racon`` block) under
+a traffic mix (``benchmark/traffic/<traffic>.json``: the input formats
+and how jobs arrive); its correctness limits are
+``benchmark/limits/<cell>.json``, and each per-layer metric is read by
+``benchmark/metrics/<metric>.py``. The harness finds all of them by the
+names in ``BENCHMARK.json``, so a new cell, configuration, mix or
+metric is a new file and an entry there, never an edit here. Both
+kinds of racon job are data:
+
+- contig polishing (kC): a mix's ``overlaps`` is ``paf`` or ``sam``,
+  read-to-draft overlaps, and the program prints one record per contig
+  of the configuration;
+- read self-correction (kF): the configuration's ``racon`` block says
+  ``"fragment_correction": true`` (the CLI's ``-f``), the mix's
+  ``overlaps`` is ``ava_paf`` (all-vs-all overlaps among the reads,
+  every read a target, ``benchmark/gen/ava.py``), and the program
+  prints each corrected read as ``<read>r``; the contigs are the
+  stretches of genome the reads are drawn from.
 
 A run (``run``):
 
@@ -26,8 +37,9 @@ A run (``run``):
    window: about 130 s on four chips); under a pool, warm-up is that
    job polished once;
 3. after the window: read the device's peak memory, compare every timed
-   job's polished contigs with their truths (``benchmark/reference.py``)
-   and print the result line.
+   job's polished records with their truths, found by the names the
+   program prints (``benchmark/reference.py``), and print the result
+   line.
 """
 
 from __future__ import annotations
@@ -182,11 +194,17 @@ class CompileCounter:
 # -------------------------------------------------------------------- jobs
 
 def cli_argv(cell: Dict, paths: List[str]) -> List[str]:
+    """The CLI's arguments for a job of the cell: racon's settings from
+    the configuration's ``racon`` block, and ``-f`` (read
+    self-correction, every read a target) where it says
+    ``"fragment_correction": true``."""
     r = cell["config"]["racon"]
+    fragment = ["-f"] if r.get("fragment_correction") else []
     return ["--backend", BACKEND, *chip_argv(cell["chips"]),
             "-w", str(r["window_length"]), "-q", str(r["quality_threshold"]),
             "-e", str(r["error_threshold"]), "-m", str(r["match"]),
-            "-x", str(r["mismatch"]), "-g", str(r["gap"]), *paths]
+            "-x", str(r["mismatch"]), "-g", str(r["gap"]), *fragment,
+            *paths]
 
 
 @contextlib.contextmanager
@@ -272,13 +290,14 @@ def polish(cell: Dict, job: Dict, extra: List[str] = ()) -> Dict:
 def make_job(cell: Dict, key: int, stream: int, index: int,
              dirname: str) -> Dict:
     """Job ``index`` of ``stream`` drawn from ``key``, written to the
-    run's directory ``dirname``."""
+    run's directory ``dirname``: its paths, target bases, the truths of
+    the records due back, keyed by the names the program prints, and,
+    where the program may drop some records (read self-correction), the
+    truths of those under ``optional``."""
     d = os.path.join(WORK_DIR, cell["name"], dirname)
-    job = genome.make_job(cell["config"], cell["traffic"],
-                          genome.job_rng(key, stream, index), d)
-    names = [c["name"] for c in cell["config"]["contigs"]]
-    return {"dir": d, "paths": job["paths"], "draft_bp": job["draft_bp"],
-            "truths": dict(zip(names, job["truths"]))}
+    return {"dir": d, **genome.make_job(cell["config"], cell["traffic"],
+                                        genome.job_rng(key, stream, index),
+                                        d)}
 
 
 def warmup_job(cell: Dict, seed: int, k: int) -> Dict:
@@ -336,10 +355,19 @@ def check(cell: Dict, jobs: List[Dict]) -> Dict:
       that no polished base reached counted among them;
     - ``block_pct``: the most errors per 100 bases in any
       ``reference.REPORT_BLOCK`` bases of any job.
+
+    Records a job may drop (its ``optional`` truths: corrected reads
+    with too few overlaps) are neither compared nor charged when
+    absent; ``optional`` counts them and those that came back.
     """
     lim = cell["limits"]
     missing, err_pct, block_pct = 0, 0.0, 0.0
+    optional = {"reads": 0, "back": 0}
     for job in jobs:
+        opt = job.get("optional", {})
+        optional["reads"] += len(opt)
+        back = job["contigs"] or {}
+        optional["back"] += sum(1 for n in opt if back.get(n))
         if job["contigs"] is None:
             missing += len(job["truths"])
             continue
@@ -354,7 +382,10 @@ def check(cell: Dict, jobs: List[Dict]) -> Dict:
     numbers = {k: {"value": values[k], "limit": limits[k]} for k in limits}
     ok = bool(jobs) and all(v["value"] <= v["limit"]
                             for v in numbers.values())
-    return {"correct": ok, "numbers": numbers}
+    verdict = {"correct": ok, "numbers": numbers}
+    if any("optional" in job for job in jobs):
+        verdict["optional"] = optional
+    return verdict
 
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
@@ -494,6 +525,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
             device["busy_by_chip"] = red["busy_by_chip"]
             result["breakdown"] = {"device_ops": red["device_ops"],
                                    "idle_gaps": red["idle_gaps"]}
+    if "optional" in verdict:
+        result["optional"] = verdict["optional"]
+        print(f"optional records {verdict['optional']['reads']} back "
+              f"{verdict['optional']['back']}", file=sys.stderr)
     result["checks"] = verdict["numbers"]
     for k, v in verdict["numbers"].items():
         print(f"check {k} {v['value']} limit {v['limit']}", file=sys.stderr)

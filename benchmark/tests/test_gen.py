@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from benchmark import harness, reference
-from benchmark.gen import genome
-from benchmark.tests.helpers import small_config, small_root
+from benchmark.gen import ava, genome
+from benchmark.tests.helpers import KF_FIXTURE, small_config, small_root
 
 RC = genome.RC
 
@@ -221,9 +221,170 @@ def test_a_small_yeast_clr_sam_job_polishes_through_the_cli(tmp_path,
                          {"dir": os.path.dirname(job["paths"][0]),
                           "paths": job["paths"]})
     assert res["failed"] is None
-    r = reference.compare_job(res["contigs"], dict(zip(names, job["truths"])))
+    assert list(job["truths"]) == names
+    r = reference.compare_job(res["contigs"], job["truths"])
     assert r["missing"] == []
     with open(job["paths"][2], "rb") as f:
         draft = reference.compare_job(reference.parse_fasta(f.read()),
-                                      dict(zip(names, job["truths"])))
+                                      job["truths"])
     assert r["errors"] * 2 < draft["errors"]
+
+
+# ------------------------------------------------ the kC cells, pinned
+
+with open(os.path.join(harness.BENCH_DIR, "tests", "data",
+                       "kc_jobs.json")) as _f:
+    # The sha256 of each file (and of the truths, in the contigs'
+    # order) of timed job 0 drawn from key 2**31 + 7, at each cell's
+    # own size and cut to 15 kb, and the CLI's arguments: what the kC
+    # cells measure, which no change for other cells may move.
+    KC_JOBS = json.load(_f)
+
+
+@pytest.mark.parametrize("size", ["small", "full"])
+@pytest.mark.parametrize("name", sorted(KC_JOBS))
+def test_the_kc_cells_jobs_and_arguments_are_as_they_were(tmp_path, name,
+                                                          size):
+    import hashlib
+    spec = harness.load_spec()
+    cell = harness.find_cell(spec, name)
+    cfg = cell["config"] if size == "full" else \
+        small_config(cell["config"]["name"], 15_000)
+    job = genome.make_job(cfg, cell["traffic"],
+                          genome.job_rng(2**31 + 7, genome.TIMED_STREAM, 0),
+                          str(tmp_path))
+    got = {}
+    for path in job["paths"]:
+        with open(path, "rb") as f:
+            got[os.path.basename(path)] = hashlib.sha256(f.read()).hexdigest()
+    got["truths"] = hashlib.sha256(
+        b"".join(job["truths"][c["name"]] for c in cfg["contigs"])
+    ).hexdigest()
+    got["draft_bp"], got["reads"] = job["draft_bp"], job["reads"]
+    assert got == KC_JOBS[name][size]
+    assert harness.cli_argv(cell, [os.path.basename(p) for p in job["paths"]]
+                            ) == KC_JOBS[name]["argv"]
+
+
+# ------------------------------------------------ all-vs-all (kF) jobs
+
+AVA = {"overlaps": "ava_paf", "reads": "fastq"}
+
+
+def _kf_config(**change) -> dict:
+    with open(os.path.join(KF_FIXTURE, "configs", "kF_small.json")) as f:
+        return {**json.load(f), **change}
+
+
+def _paf(path):
+    with open(path) as f:
+        return [ln.split("\t") for ln in f.read().splitlines()]
+
+
+def _arc(read, bp: int) -> np.ndarray:
+    """The truth bases of the circle that ``read`` covers, as a mask."""
+    mask = np.zeros(bp, bool)
+    mask[(read.start + np.arange(read.span)) % bp] = True
+    return mask
+
+
+def test_ava_reports_each_pair_that_shares_enough_truth_once(tmp_path):
+    cfg = _kf_config()
+    job = genome.make_job(cfg, AVA, genome.job_rng(2**31 + 9, 2, 0),
+                          str(tmp_path))
+    assert job["paths"][0] == job["paths"][2]
+    layout = {r.name: r for r in ava.read_layout(cfg)}
+    assert job["reads"] == len(layout) > 20
+    bp = cfg["contigs"][0]["bp"]
+    recs = _paf(job["paths"][1])
+    pairs = [frozenset((rec[0], rec[5])) for rec in recs]
+    assert all(len(p) == 2 for p in pairs)          # never a self pair
+    assert len(set(pairs)) == len(pairs)             # each pair once
+    arcs = {n: _arc(r, bp) for n, r in layout.items()}
+    assert set(pairs) == {
+        frozenset((a, b)) for a in layout for b in layout
+        if a < b and (arcs[a] & arcs[b]).sum() >= genome.MIN_OVERLAP}
+    for rec in recs:
+        q, t = layout[rec[0]], layout[rec[5]]
+        assert q.name < t.name                       # as minimap2 -X
+        assert rec[4] == ("-" if q.reverse != t.reverse else "+")
+        assert int(rec[9]) == (arcs[q.name] & arcs[t.name]).sum()
+
+
+def test_ava_read_names_carry_no_position():
+    layout = ava.read_layout(_kf_config())
+    order = [int(r.name[1:]) for r in sorted(layout, key=lambda r: r.start)]
+    assert sorted(order) == list(range(len(layout)))
+    assert order != sorted(order) and order != sorted(order)[::-1]
+
+
+def test_ava_coordinates_span_the_shared_truth_in_both_reads(tmp_path):
+    # Without errors each range spells the shared truth exactly, in the
+    # orientation the record's strand says; with them, the two ranges
+    # align as two 10% reads do, and on the other strand they do not.
+    exact = _kf_config(read_error={"sub": 0, "ins": 0, "del": 0})
+    for cfg in (exact, _kf_config()):
+        job = genome.make_job(cfg, AVA, genome.job_rng(2**31 + 9, 2, 0),
+                              str(tmp_path / ("exact" if cfg is exact
+                                              else "noisy")))
+        reads = _fastq(job["paths"][0])
+        for rec in _paf(job["paths"][1])[:40]:
+            q0, q1, t0, t1 = (int(rec[k]) for k in (2, 3, 7, 8))
+            assert 0 <= q0 < q1 <= int(rec[1]) == len(reads[rec[0]])
+            assert 0 <= t0 < t1 <= int(rec[6]) == len(reads[rec[5]])
+            q = np.frombuffer(reads[rec[0]][q0:q1], np.uint8)
+            if rec[4] == "-":
+                q = RC[q][::-1]
+            t = reads[rec[5]][t0:t1]
+            if cfg is exact:
+                assert q.tobytes() == t and len(t) == int(rec[9])
+            elif len(t) < 1500:
+                ed, _ = reference.prefix_distance(q.tobytes(), t)
+                assert ed < 0.3 * len(t)
+                ed, _ = reference.prefix_distance(RC[q][::-1].tobytes(), t)
+                assert ed > 0.4 * len(t)
+
+
+def test_ava_truths_are_the_reads_without_errors(tmp_path):
+    exact = _kf_config(read_error={"sub": 0, "ins": 0, "del": 0})
+    job = genome.make_job(exact, AVA, genome.job_rng(5, 2, 0), str(tmp_path))
+    reads = _fastq(job["paths"][0])
+    assert {**job["truths"], **job["optional"]} == \
+        {n + "r": s for n, s in reads.items()}
+    assert job["draft_bp"] == sum(len(s) for s in reads.values())
+
+
+def test_reads_laid_on_a_circle_see_level_coverage():
+    # The proposed kF cell's reads: ecoli_ont's profiles over a 45 kb
+    # stretch at 30x. A linear stretch would fall to about half its
+    # coverage at each end.
+    with open(os.path.join(harness.BENCH_DIR, "configs",
+                           "ecoli_ont.json")) as f:
+        ont = json.load(f)
+    bp, cov = 45_000, 30
+    cfg = _kf_config(contigs=[{"name": "s", "bp": bp}], coverage=cov,
+                     read_length=ont["read_length"],
+                     reverse_share=ont["reverse_share"])
+    depth = sum(_arc(r, bp).astype(int) for r in ava.read_layout(cfg))
+    assert abs(depth.mean() - cov) < 0.05 * cov
+    assert 0.7 * cov <= depth.min() and depth.max() <= 1.4 * cov
+    assert depth[0] >= 0.7 * cov and depth[-1] >= 0.7 * cov
+
+
+def test_ava_reads_due_back_have_three_overlaps_in_every_window(tmp_path):
+    cfg = _kf_config()
+    job = genome.make_job(cfg, AVA, genome.job_rng(2**31 + 9, 2, 0),
+                          str(tmp_path))
+    reads = _fastq(job["paths"][0])
+    recs = _paf(job["paths"][1])
+    w = cfg["racon"]["window_length"]
+    due = set()
+    for name, seq in reads.items():
+        counts = [sum(1 for rec in recs if rec[5] == name
+                      and int(rec[7]) < k + w and int(rec[8]) > k)
+                  for k in range(0, len(seq), w)]
+        if min(counts) >= 3:
+            due.add(name + "r")
+    assert set(job["truths"]) == due
+    assert set(job["optional"]) == {n + "r" for n in reads} - due
+    assert due and job["optional"]
