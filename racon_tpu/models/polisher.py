@@ -323,10 +323,17 @@ class Polisher:
                     pairs.append((encode_bases(bytes(q)),
                                   encode_bases(bytes(t))))
                 bases = sum(len(q) + len(t) for q, t in pairs)
-                sp.add(bases=bases)
                 reg.inc("align_native_bases", bases)
-                for o, ops in zip(pending, aligner.align_batch(pairs)):
+                # Band cells of every pass, and those the vector pass
+                # computed (racon_tpu/native/nw.cpp).
+                cells = np.zeros((len(pairs), 2), np.int64)
+                for o, ops in zip(pending,
+                                  aligner.align_batch(pairs, cells=cells)):
                     o.cigar = ops_to_cigar(ops)
+                n_cells, n_vector = (int(c) for c in cells.sum(axis=0))
+                sp.add(bases=bases, cells=n_cells, vector_cells=n_vector)
+                reg.inc("align_native_cells", n_cells)
+                reg.inc("align_native_vector_cells", n_vector)
         step = len(overlaps) // 20
         with obs_metrics.timed_span("align", "bp_walk",
                                     overlaps=len(overlaps)) as sp:

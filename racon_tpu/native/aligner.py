@@ -42,14 +42,30 @@ def _load():
             ctypes.c_int32, ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
             ctypes.c_int32, ctypes.c_int32,
             ctypes.POINTER(ctypes.c_uint8), ctypes.POINTER(ctypes.c_int64),
-            ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int64),
         ]
+        lib.racon_nw_vector_lanes.restype = ctypes.c_int32
+        lib.racon_nw_vector_lanes.argtypes = []
         _lib = lib
     return _lib
 
 
+def vector_lanes() -> int:
+    """Lanes of the native aligner's vector pass on this host (0: the
+    scalar pass alone)."""
+    return int(_load().racon_nw_vector_lanes())
+
+
 def _u8ptr(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8))
+
+
+def _cells_ptr(cells: np.ndarray, n: int):
+    if (cells.dtype != np.int64 or cells.shape != (n, 2)
+            or not cells.flags.c_contiguous):
+        raise ValueError("cells must be a C-contiguous int64 array of "
+                         f"shape ({n}, 2)")
+    return cells.ctypes.data_as(ctypes.POINTER(ctypes.c_int64))
 
 
 class NativeAligner:
@@ -90,9 +106,13 @@ class NativeAligner:
                 f"(lq={len(qa)}, lt={len(ta)})")
         return out[:n]
 
-    def align_batch(self, pairs: Sequence[Tuple[np.ndarray, np.ndarray]]
-                    ) -> List[np.ndarray]:
-        """One FFI call for a whole batch of (q_codes, t_codes) pairs."""
+    def align_batch(self, pairs: Sequence[Tuple[np.ndarray, np.ndarray]],
+                    cells: Optional[np.ndarray] = None) -> List[np.ndarray]:
+        """One FFI call for a whole batch of (q_codes, t_codes) pairs.
+
+        ``cells`` (optional, int64 of shape (len(pairs), 2)) receives
+        each pair's band cells over all its passes, and those of them
+        the vector pass computed."""
         lib = _load()
         n = len(pairs)
         if n == 0:
@@ -118,7 +138,8 @@ class NativeAligner:
             t_len.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
             n, self.match, self.mismatch, self.gap, self.band, self.threads,
             _u8ptr(ops_out), ops_off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            ops_len.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)))
+            ops_len.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+            None if cells is None else _cells_ptr(cells, n))
         if rc != 0:
             raise RuntimeError(
                 f"[racon_tpu::native] error: batch alignment failed at "

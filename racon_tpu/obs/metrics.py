@@ -1123,6 +1123,8 @@ METRIC_SPECS = (
     ("adaptive_rounds_scheduled", MERGE_SUM, "adaptive_rounds_scheduled"),
     ("align_bases_total", MERGE_SUM, "align_bases_total"),
     ("align_native_bases", MERGE_SUM, "align_native_bases"),
+    ("align_native_cells", MERGE_SUM, "align_native_cells"),
+    ("align_native_vector_cells", MERGE_SUM, "align_native_vector_cells"),
     ("align_seconds_*", MERGE_SUM, "align_seconds_"),
     ("align_walk_overlaps", MERGE_SUM, "align_walk_overlaps"),
     ("ava_buckets", MERGE_LAST, "ava_buckets"),

@@ -111,3 +111,30 @@ def test_cli_trace_restores_previous_tracer(tmp_path, capsys, clean_obs):
     assert not isinstance(obs_trace.get_tracer(), obs_trace.Tracer)
     assert sorted(p.name for p in tmp_path.glob("*.jsonl*")) == \
         ["traced.jsonl"]
+
+
+def test_native_redo_counts_band_cells(tmp_path, capsys, clean_obs):
+    """On the native backend every overlap is redone on the host; the
+    ``native_redo`` span and the registry count the band cells, all of
+    them in the vector pass where the host has one (every first band is
+    at least 257 cells wide)."""
+    from racon_tpu.native.aligner import vector_lanes
+    d = _write_dataset(tmp_path, n_reads=6, read_len=2000, seed=7,
+                       rate=0.1, draft_len=4000)
+    trace = tmp_path / "spans.jsonl"
+    obs_metrics.reset()
+    rc = cli.main(["--backend", "native", "--trace", str(trace),
+                   f"{d}/reads.fasta.gz", f"{d}/overlaps.paf.gz",
+                   f"{d}/draft.fasta.gz"])
+    assert rc == 0 and capsys.readouterr().out.startswith(">")
+    reg = obs_metrics.registry().snapshot()
+    assert reg["align_native_bases"] == reg["align_bases_total"] > 0
+    # At least one band of lq + 1 rows of 257 cells per overlap.
+    assert reg["align_native_cells"] >= 6 * 257 * 1000
+    want = reg["align_native_cells"] if vector_lanes() else 0
+    assert reg["align_native_vector_cells"] == want
+    recs = [json.loads(ln) for ln in trace.read_text().splitlines()]
+    redo, = [r for r in recs if r["ev"] == "span" and r["kind"] == "align"
+             and r["name"] == "native_redo"]
+    assert (redo["cells"], redo["vector_cells"]) == \
+        (reg["align_native_cells"], reg["align_native_vector_cells"])
