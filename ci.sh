@@ -1,12 +1,9 @@
 #!/usr/bin/env bash
 # CI entry point (reference parity: .travis.yml:32-37 runs racon_test on
-# every build). Default tier runs the full CPU suite, the flagship
+# every build). Default tier runs the full CPU suite (which includes the
+# two-shape device-engine test, tests/test_poa.py), the flagship
 # device-engine golden (ED vs the reference acceptance value — a gate,
-# not a docstring), the multi-chip dryrun, and the two-shape
-# device-engine smoke — the regression class that shipped in round 3
-# (two differently-shaped consensus runs in one process crashed with
-# INVALID_ARGUMENT; reproducible on the CPU backend, see
-# scripts/tpu_two_shape_repro.py).
+# not a docstring), and the multi-chip dryrun.
 #
 #   ci.sh          default tier
 #   ci.sh --full   additionally runs every opt-in 'ava' golden
@@ -43,9 +40,6 @@ python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
 echo "[ci] interpreter-mode kernel + dual-column walk smoke"
 python -m pytest tests/test_kernels_interpret.py tests/test_colwalk.py \
   -q -m ''
-
-echo "[ci] two-shape device-engine smoke"
-python scripts/two_shape_smoke.py
 
 echo "[ci] long-read smoke (20 kb tiled device path, zero native fallbacks)"
 python scripts/ultralong_smoke.py

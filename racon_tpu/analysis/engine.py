@@ -51,8 +51,8 @@ _SKIP_DIRS = {"__pycache__", ".git", ".pytest_cache"}
 
 
 def discover_files(root: str) -> List[str]:
-    """Default lint corpus: every .py under racon_tpu/ and scripts/,
-    plus bench.py. tests/ are deliberately out — fixtures under
+    """Default lint corpus: every .py under racon_tpu/ and scripts/.
+    tests/ are deliberately out — fixtures under
     tests/fixtures/analysis/ carry seeded violations."""
     out: List[str] = []
     for top in ("racon_tpu", "scripts"):
@@ -63,9 +63,6 @@ def discover_files(root: str) -> List[str]:
             for fn in sorted(filenames):
                 if fn.endswith(".py"):
                     out.append(os.path.join(dirpath, fn))
-    bench = os.path.join(root, "bench.py")
-    if os.path.exists(bench):
-        out.append(bench)
     return out
 
 

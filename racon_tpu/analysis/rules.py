@@ -113,7 +113,7 @@ def check_env_contract(ctx: Context) -> Iterator[Finding]:
     registry = ctx.env_registry()
 
     # ENV001/ENV002: every read resolves through a declared spec.
-    for path in ctx.scoped("racon_tpu/", "scripts/", "bench.py"):
+    for path in ctx.scoped("racon_tpu/", "scripts/"):
         rel = ctx.rel(path)
         if rel == "racon_tpu/utils/envspec.py":
             continue
@@ -163,7 +163,7 @@ def check_env_contract(ctx: Context) -> Iterator[Finding]:
     # Name -> is it read anywhere (textual: the code keeps ENV_*
     # constants, so the full name appears at its declaration site).
     corpus = {ctx.rel(f): ctx.source(f)
-              for f in ctx.scoped("racon_tpu/", "scripts/", "bench.py")
+              for f in ctx.scoped("racon_tpu/", "scripts/")
               if ctx.rel(f) != spec_rel}
     blob = "\n".join(corpus.values())
     docs = ctx.doc_files()
@@ -173,8 +173,8 @@ def check_env_contract(ctx: Context) -> Iterator[Finding]:
         if name not in blob:
             yield Finding(
                 "ENV003", "error", spec_rel, decl_line(name),
-                f"declared gate {name} is read nowhere in racon_tpu/, "
-                f"scripts/, or bench.py: delete the declaration")
+                f"declared gate {name} is read nowhere in racon_tpu/ "
+                f"or scripts/: delete the declaration")
         # ENV004: declared but missing from its doc file.
         doc = getattr(spec, "doc", None) or (
             spec.get("doc") if isinstance(spec, dict) else None)
@@ -455,7 +455,7 @@ def check_span_schema(ctx: Context) -> Iterator[Finding]:
     legal = set(required) | free
     emitted: Dict[str, str] = {}
 
-    for path in ctx.scoped("racon_tpu/", "bench.py"):
+    for path in ctx.scoped("racon_tpu/"):
         rel = ctx.rel(path)
         tree = ctx.tree(path)
         if tree is None:
@@ -701,7 +701,7 @@ def check_histogram(ctx: Context) -> Iterator[Finding]:
     # HIS001 (per-file direction): every recorded family has declared
     # bucket bounds — record_hist raises at runtime otherwise, and the
     # linter catches the site before any test exercises it.
-    for path in ctx.scoped("racon_tpu/", "scripts/", "bench.py"):
+    for path in ctx.scoped("racon_tpu/", "scripts/"):
         rel = ctx.rel(path)
         tree = ctx.tree(path)
         if tree is None:
@@ -723,7 +723,7 @@ def check_histogram(ctx: Context) -> Iterator[Finding]:
     metrics_src = ""
     export_src = ""
     corpus = []
-    for f in ctx.scoped("racon_tpu/", "bench.py"):
+    for f in ctx.scoped("racon_tpu/"):
         rel = ctx.rel(f)
         if rel == metrics_rel:
             metrics_src = ctx.source(f)
@@ -752,7 +752,7 @@ def check_histogram(ctx: Context) -> Iterator[Finding]:
             yield Finding(
                 "HIS001", "error", metrics_rel, bucket_line(name),
                 f"HIST_BUCKETS family {name!r} is recorded nowhere "
-                f"(no record_hist call in racon_tpu/ or bench.py)")
+                f"(no record_hist call in racon_tpu/)")
     for pattern in sorted(hist_specs):
         if pattern not in buckets:
             yield Finding(

@@ -530,7 +530,7 @@ class Polisher:
         if telem is not None and telem.windows:
             # One source of truth: the counters go into the process
             # metrics registry, and the stderr line is formatted from
-            # the same registry keys bench.py serializes.
+            # the same registry keys the trace snapshot carries.
             from racon_tpu.obs.metrics import (publish_sched, registry,
                                                sched_summary_line)
             publish_sched(telem, registry())

@@ -7,7 +7,7 @@
   (``worker_id``/``shard``/``run_fp``) via ``set_context``.
 - :mod:`racon_tpu.obs.metrics` — process-wide counter registry: the
   single source for the polisher's stderr scheduler summary,
-  ``SchedTelemetry.as_extras()``, and bench.py's JSON extras, plus
+  ``SchedTelemetry.as_extras()``, and the benchmark's readers, plus
   h2d/d2h transfer accounting (bytes, seconds, effective bandwidth)
   and dispatch / compile-cache counters. Every key has an explicit
   fleet merge kind (``merge_kind``: sum/max/last).

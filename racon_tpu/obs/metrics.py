@@ -1,17 +1,17 @@
 """Process-wide metrics registry — the single reporting source.
 
-Three consumers used to format/serialize their own counters and could
-drift: the polisher's stderr scheduler summary (utils/logger.py), the
-scheduler's ``SchedTelemetry.as_extras()``, and bench.py's JSON extras.
-They now all read one registry: :func:`publish_sched` writes the
-canonical ``sched_*`` keys, :func:`sched_summary_line` formats the
-stderr line from them, and :func:`transfer_extras` derives the
-h2d/d2h byte / second / effective-bandwidth numbers recorded at the
-transfer choke points (parallel/dispatch.py, ops/device_poa.py,
+Every consumer reads this one registry: the polisher's stderr
+scheduler summary (utils/logger.py), the scheduler's
+``SchedTelemetry.as_extras()``, the snapshot the CLI writes into the
+trace on exit, the fleet aggregator and OpenMetrics exporter, and the
+benchmark's per-layer readers (benchmark/metrics/).
+:func:`publish_sched` writes the canonical ``sched_*`` keys, :func:`sched_summary_line` formats the
+stderr line from them, and :func:`transfer_extras` derives the h2d/d2h
+byte / second / effective-bandwidth numbers recorded at the transfer
+choke points (parallel/dispatch.py, ops/device_poa.py,
 sched/scheduler.py).
 
-Counter conventions (all keys appear in bench extras, metric_version 3;
-docs/OBSERVABILITY.md documents the full set):
+Counter conventions (docs/OBSERVABILITY.md documents the full set):
 
 - ``h2d_bytes`` / ``h2d_s`` / ``h2d_transfers`` — bytes shipped to the
   device, wall seconds of the ``device_put`` calls, call count.
@@ -246,7 +246,7 @@ def record_flag_pull(nbytes: int, seconds: float,
 
 def transfer_extras(reg: Optional[MetricsRegistry] = None
                     ) -> Dict[str, object]:
-    """Derived transfer numbers for bench extras / reports."""
+    """Derived transfer numbers for reports."""
     reg = reg if reg is not None else _REGISTRY
     out: Dict[str, object] = {}
     for d in ("h2d", "d2h"):
@@ -364,19 +364,6 @@ def record_ckpt(event: str, tid: int, nbytes: int,
                               bytes=int(nbytes))
 
 
-def resilience_extras(reg: Optional[MetricsRegistry] = None
-                      ) -> Dict[str, object]:
-    """The registry's res_* keys as a JSON-ready dict (bench extras /
-    obs_report "Resilience" section). Empty when nothing resilience-
-    related happened, so quiet runs stay quiet."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("res_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
-
-
 # ----------------------------------------------------- distributed work
 
 def record_dist(event: str, shard, worker, value: float = 1,
@@ -402,19 +389,6 @@ def set_dist(key: str, value: object,
     ``dist_n_targets`` — fleet shape, not counters)."""
     reg = reg if reg is not None else _REGISTRY
     reg.set(f"dist_{key}", value)
-
-
-def dist_extras(reg: Optional[MetricsRegistry] = None
-                ) -> Dict[str, object]:
-    """The registry's dist_* keys as a JSON-ready dict (bench extras
-    metric_version 8 / obs_report "Distributed" section). Empty when no
-    ledger ran, so single-process runs stay quiet."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("dist_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
 
 
 # ------------------------------------------- overlap-alignment counters
@@ -446,19 +420,6 @@ def record_ovl(device_jobs: int, native_jobs: int, tiles: int,
     reg.apply(_mutate)
 
 
-def ovl_extras(reg: Optional[MetricsRegistry] = None
-               ) -> Dict[str, object]:
-    """The registry's ovl_* keys as a JSON-ready dict (bench extras
-    metric_version 7 / obs_report). Empty when no overlap alignment
-    ran."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("ovl_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
-
-
 @contextlib.contextmanager
 def timed_span(kind: str, name: str, **attrs):
     """A tracer span that also times itself into the registry.
@@ -485,26 +446,12 @@ def record_redo(device_windows: int, host_windows: int,
     flagged windows the on-device second pass resolved,
     ``host_windows`` windows still unresolved after it (saturation
     class, or certificate failure at the widened band) that fall back
-    to the host consensus. Zero host windows at bench geometry is the
-    acceptance criterion the redo smoke pins."""
+    to the host consensus. Zero host windows on the redo smoke's
+    windows is the acceptance criterion it pins."""
     reg = reg if reg is not None else _REGISTRY
     reg.inc("redo_passes")
     reg.inc("redo_device_windows", int(device_windows))
     reg.inc("redo_host_windows", int(host_windows))
-
-
-def redo_extras(reg: Optional[MetricsRegistry] = None
-                ) -> Dict[str, object]:
-    """The registry's redo_* keys plus the ``walk_chain_len`` gauge as a
-    JSON-ready dict (bench extras metric_version 9 / obs_report "Redo"
-    section). ``walk_chain_len`` reports even when no redo fired — it is
-    the traceback critical-path gauge, set at every chunk dispatch."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("redo_") or k == "walk_chain_len":
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
 
 
 # ------------------------------------------------------- ingest plane
@@ -565,29 +512,6 @@ def set_ingest_fraction(wall_s: float,
         reg.set("ingest_fraction_of_wall", round(wait / wall_s, 4))
 
 
-def ingest_extras(reg: Optional[MetricsRegistry] = None
-                  ) -> Dict[str, object]:
-    """The registry's ingest_* keys as a JSON-ready dict (bench extras
-    metric_version 11 / obs_report "ingest:" section), plus derived
-    ``ingest_mb_per_sec`` (decompressed MB over inflate+parse seconds).
-    Empty when no ingest accounting ran."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("ingest_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    if not out:
-        return out
-    raw = float(reg.get("ingest_raw_bytes", 0.0)) or float(
-        reg.get("ingest_bytes_out", 0.0))
-    busy = float(reg.get("ingest_inflate_s", 0.0)) + float(
-        reg.get("ingest_parse_s", 0.0))
-    if raw > 0 and busy > 0:
-        out["ingest_mb_per_sec"] = round(raw / busy / 1e6, 2)
-    out["ingest_seconds"] = round(busy, 4)
-    return out
-
-
 # ------------------------------------------------------ pipeline gauges
 
 def record_stage(name: str, busy_s: float, stall_in_s: float,
@@ -628,8 +552,8 @@ def record_pipeline_wall(seconds: float,
 
 def pipeline_extras(reg: Optional[MetricsRegistry] = None
                     ) -> Dict[str, object]:
-    """The registry's pipe_* keys as a JSON-ready dict (bench extras /
-    obs_report "Pipeline" section), plus the derived overlap efficiency
+    """The registry's pipe_* keys as a JSON-ready dict (folded into
+    the CLI's exit snapshot / obs_report "Pipeline" section), plus the derived overlap efficiency
     = device-busy (compute stage) / pipeline wall. Empty when no
     pipeline ran."""
     reg = reg if reg is not None else _REGISTRY
@@ -666,7 +590,7 @@ def record_walk(walk_s: float, overlap_s: float, dispatches: int,
     - ``enabled``      whether the decoupled path was active at all.
 
     The derived ``walk_hidden_fraction`` = overlap / walk seconds is
-    the bench/ablation headline (ISSUE 14 acceptance gate)."""
+    the walk overlap smoke's headline."""
     reg = reg if reg is not None else _REGISTRY
     reg.set("walk_async_enabled", int(bool(enabled)))
     reg.inc("walk_seconds", float(walk_s))
@@ -684,8 +608,8 @@ def record_walk(walk_s: float, overlap_s: float, dispatches: int,
 
 def walk_extras(reg: Optional[MetricsRegistry] = None
                 ) -> Dict[str, object]:
-    """The registry's walk_* keys as a JSON-ready dict (bench extras /
-    ablation report). Empty when no streaming run recorded walk
+    """The registry's walk_* keys as a JSON-ready dict (folded into
+    the CLI's exit snapshot). Empty when no streaming run recorded walk
     telemetry (record_walk never ran)."""
     reg = reg if reg is not None else _REGISTRY
     if reg.get("walk_async_enabled", None) is None:
@@ -706,8 +630,8 @@ def record_serve_job(event: str, job: str, tenant: str,
     ``submitted`` / ``completed`` / ``failed`` / ``cancelled`` /
     ``resumed`` — each lands as the counter ``serve_jobs_<event>``
     plus a ``serve`` trace span carrying the job id, tenant, and the
-    job's trace context (``"-"``/0 when the caller has none, e.g. the
-    bench driving the batcher directly). Returns the span id — the
+    job's trace context (``"-"``/0 when the caller has none, e.g. a
+    test driving the batcher directly). Returns the span id — the
     ``submitted`` span is the root the daemon mints the job's
     :class:`~racon_tpu.obs.trace.TraceContext` from."""
     reg = reg if reg is not None else _REGISTRY
@@ -786,19 +710,6 @@ def set_serve_rate(jobs_per_min: float,
     reg.apply(_mutate)
 
 
-def serve_extras(reg: Optional[MetricsRegistry] = None
-                 ) -> Dict[str, object]:
-    """The registry's serve_* keys as a JSON-ready dict (bench extras
-    metric_version 13 / obs_report "server:" section). Empty when no
-    daemon/batcher ran, so CLI runs stay quiet."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("serve_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
-
-
 # ------------------------------------------------------------ gate plane
 
 #: Gateway lifecycle events -> the counter each lands in. The mapping
@@ -852,32 +763,6 @@ def set_gate_fleet_target(n: int,
     reg.set("gate_fleet_target", int(n))
 
 
-def set_gate_rate(jobs_per_min: float,
-                  compile_skip_s: Optional[float] = None,
-                  reg: Optional[MetricsRegistry] = None) -> None:
-    """Set the gateway throughput gauges (bench metric_version 16):
-    fleet-path jobs/min, and — when measured — the wall seconds a
-    freshly spawned worker skipped by hitting the shared jaxcache warm
-    pool instead of compiling cold."""
-    reg = reg if reg is not None else _REGISTRY
-    reg.set("gate_fleet_jobs_per_min", round(float(jobs_per_min), 4))
-    if compile_skip_s is not None:
-        reg.set("gate_compile_skip_s", round(float(compile_skip_s), 4))
-
-
-def gate_extras(reg: Optional[MetricsRegistry] = None
-                ) -> Dict[str, object]:
-    """The registry's gate_* keys as a JSON-ready dict (bench extras
-    metric_version 16 / obs_report "gateway:" section). Empty when no
-    gateway ran, so plain daemon and CLI runs stay quiet."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("gate_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
-
-
 # ------------------------------------------------------------ ava plane
 
 
@@ -894,34 +779,6 @@ def record_ava_plan(plan,
     reg.set("ava_quantum", int(plan.quantum))
     reg.set("ava_compile_budget", int(plan.budget))
     reg.set("ava_pad_frac", round(float(plan.pad_frac), 4))
-
-
-def set_ava_bench(reads_per_sec: float, peak_rss_mb: float,
-                  manifest_bytes_per_target: float,
-                  reg: Optional[MetricsRegistry] = None) -> None:
-    """Set the ava bench gauges (bench metric_version 17): corrected
-    reads per wall second, the run's peak resident set, and manifest
-    bytes per committed target — the v2 segmented manifest's
-    amortization, which v1's one-record-per-target format holds at
-    ~100 regardless of scale."""
-    reg = reg if reg is not None else _REGISTRY
-    reg.set("ava_reads_per_sec", round(float(reads_per_sec), 4))
-    reg.set("ava_peak_rss_mb", round(float(peak_rss_mb), 4))
-    reg.set("ava_manifest_bytes_per_target",
-            round(float(manifest_bytes_per_target), 4))
-
-
-def ava_extras(reg: Optional[MetricsRegistry] = None
-               ) -> Dict[str, object]:
-    """The registry's ava_* keys as a JSON-ready dict (bench extras
-    metric_version 17 / obs_report "ava:" section). Empty when no ava
-    planning ran, so kC runs stay quiet."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("ava_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
 
 
 # --------------------------------------------------- result cache plane
@@ -973,20 +830,6 @@ def record_cache(tier: str, outcome: str, n: int = 1, nbytes: int = 0,
                               bytes=int(nbytes))
 
 
-def result_cache_extras(reg: Optional[MetricsRegistry] = None
-                        ) -> Dict[str, object]:
-    """The registry's cache_* keys as a JSON-ready dict (bench extras
-    metric_version 14 / obs_report "cache:" section); named to stay
-    clear of utils/jaxcache.cache_extras, the compile-cache gauges.
-    Empty when nothing probed the result cache."""
-    reg = reg if reg is not None else _REGISTRY
-    out: Dict[str, object] = {}
-    for k, v in sorted(reg.snapshot().items()):
-        if k.startswith("cache_"):
-            out[k] = round(v, 4) if isinstance(v, float) else v
-    return out
-
-
 # ------------------------------------------------------- sched telemetry
 
 #: Canonical sched_* registry keys (docs/SCHEDULER.md documents each).
@@ -1014,7 +857,7 @@ def publish_sched(telem, reg: Optional[MetricsRegistry] = None) -> None:
 
 def sched_extras(reg: Optional[MetricsRegistry] = None
                  ) -> Dict[str, object]:
-    """The registry's sched_* keys as a JSON-ready dict (bench extras)."""
+    """The registry's sched_* keys as a JSON-ready dict."""
     reg = reg if reg is not None else _REGISTRY
     return {k: reg.get(k) for k in SCHED_KEYS}
 
@@ -1076,19 +919,13 @@ _MERGE_LAST_KEYS = frozenset({
     # re-derives from the totals on every event, so the most recent
     # snapshot wins — the cache_* hit/miss/store/evict counters sum.
     "cache_hit_ratio",
-    # Gateway gauges (racon_tpu/gateway/): the policy's latest fleet
-    # sizing decision and the bench throughput/compile-skip readings —
-    # the gate_* routed/adoption/run counters sum.
-    "gate_fleet_target", "gate_fleet_jobs_per_min",
-    "gate_compile_skip_s",
-    # Ava plane gauges (record_ava_plan / set_ava_bench above): the
-    # bucket plan is identical on every worker and the bench readings
-    # are per-run measurements, so the latest snapshot wins —
-    # ava_peak_rss_mb is listed despite its name because it lacks the
-    # ``_peak`` suffix the max rule keys on.
+    # Gateway gauge (racon_tpu/gateway/): the policy's latest fleet
+    # sizing decision — the gate_* routed/adoption/run counters sum.
+    "gate_fleet_target",
+    # Ava plane gauges (record_ava_plan above): the bucket plan is
+    # identical on every worker, so the latest snapshot wins.
     "ava_targets", "ava_buckets", "ava_quantum", "ava_compile_budget",
-    "ava_pad_frac", "ava_reads_per_sec", "ava_peak_rss_mb",
-    "ava_manifest_bytes_per_target",
+    "ava_pad_frac",
 })
 
 
@@ -1129,12 +966,8 @@ METRIC_SPECS = (
     ("align_walk_overlaps", MERGE_SUM, "align_walk_overlaps"),
     ("ava_buckets", MERGE_LAST, "ava_buckets"),
     ("ava_compile_budget", MERGE_LAST, "ava_compile_budget"),
-    ("ava_manifest_bytes_per_target", MERGE_LAST,
-     "ava_manifest_bytes_per_target"),
     ("ava_pad_frac", MERGE_LAST, "ava_pad_frac"),
-    ("ava_peak_rss_mb", MERGE_LAST, "ava_peak_rss_mb"),
     ("ava_quantum", MERGE_LAST, "ava_quantum"),
-    ("ava_reads_per_sec", MERGE_LAST, "ava_reads_per_sec"),
     ("ava_targets", MERGE_LAST, "ava_targets"),
     ("cache_bytes", MERGE_SUM, "cache_bytes"),
     ("cache_evictions_total", MERGE_SUM, "cache_evictions_total"),
@@ -1156,8 +989,6 @@ METRIC_SPECS = (
     ("fleet_target_workers", MERGE_LAST, "fleet_target_workers"),
     ("flight_dump_write_s", MERGE_SUM, "flight_dump_write_s"),
     ("flight_dumps_total", MERGE_SUM, "flight_dumps_total"),
-    ("gate_compile_skip_s", MERGE_LAST, "gate_compile_skip_s"),
-    ("gate_fleet_jobs_per_min", MERGE_LAST, "gate_fleet_jobs_per_min"),
     ("gate_fleet_target", MERGE_LAST, "gate_fleet_target"),
     ("gate_*", MERGE_SUM, "gate_routed_fleet"),
     ("h2d_bytes", MERGE_SUM, "h2d_bytes"),

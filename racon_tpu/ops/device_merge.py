@@ -68,8 +68,9 @@ NBASE = 5          # A C G T N
 # jitted function lowers as a hoisted buffer parameter on some traces, and
 # jax 0.9's execution path then under-supplies the executable ("Execution
 # supplied 11 buffers but compiled program expected 12") — the root cause
-# of the round-3 INVALID_ARGUMENT crash on TPU (BENCH_r03; repro:
-# scripts/tpu_two_shape_repro.py). A Python scalar is always inlined.
+# of the round-3 INVALID_ARGUMENT crash on TPU (guarded by
+# tests/test_poa.py::test_device_engine_two_shapes_one_process). A Python
+# scalar is always inlined.
 _HI = 2 ** 30
 
 _PREC = jax.lax.Precision.HIGHEST
@@ -261,9 +262,9 @@ def extract_votes_cols(cols, q, qw8, w_read, lt, t_off, LA: int):
     counter at U_SAT = K_INS + 1 and take the host redo route), so the
     window only spans K_INS + 1 query codes and K_INS + 1 weights around
     qstart - 1. TPU gather cost scales with the number of gathered
-    ELEMENTS, not bytes (measured round 5, scripts/ablate_gather_pack.py:
-    a 26-channel u8 stacked gather costs ~100 ms at B=6144 where a
-    3-word i32 gather costs ~30 ms), so the window ships as FOUR packed
+    ELEMENTS, not bytes (measured on the chip in round 5: a 26-channel
+    u8 stacked gather costs ~100 ms at B=6144 where a 3-word i32 gather
+    costs ~30 ms), so the window ships as FOUR packed
     i32 words per query position: 11 base codes at 3 bits each and 11
     weights at 7 bits each (weights are Phred + 1 <= 94 + 1 on any real
     FASTQ — ChunkPlan clips the encoding at 126 accordingly). The run

@@ -20,10 +20,11 @@ Semantics match PoaEngine's numpy path bit-for-bit on integer weights
 certified per lane every round by an escape-bound score check (see
 racon_tpu/ops/pallas/band_kernel.py): a lane whose banded score cannot
 provably beat every band-leaving path flags its window for re-polish on
-the unbounded host path. The dp-sharded path (device_round_sharded) is
-near-bit-identical to single-device: its one psum may reassociate f32
-vote sums, so sub-epsilon ties can break differently (tests accept rare
-single-window divergence; see tests/test_device_merge.py).
+the unbounded host path. The dp-sharded path (device_chunk_packed with a
+mesh) is near-bit-identical to single-device: its one psum per round may
+reassociate f32 vote sums, so sub-epsilon ties can break differently
+(tests accept rare single-window divergence; see
+tests/test_device_merge.py).
 """
 
 from __future__ import annotations
@@ -71,7 +72,7 @@ def _bucket_b(n: int) -> int:
 
 
 # Executable-reuse cap history, per process: every differently-shaped
-# device_round executable is a fresh multi-second XLA compile, so later
+# chunk executable is a fresh multi-second XLA compile, so later
 # runs pad up to a previously-compiled (Lq, LA) pair when one covers them
 # within 2x per dim (beyond that, recompiling is cheaper than the padded
 # compute). jax's executable cache keys on the same shapes, so a history
@@ -88,7 +89,7 @@ def run_caps(lq: int, la: int) -> Tuple[int, int]:
     """(lq_cap, la_cap) covering a run's max layer/backbone lengths, on a
     coarse grid."""
     # LA pads on a 128 grid; with the 64-slot growth slack, runs of the
-    # same workload (e.g. bench warmup vs measured, lengths ~w..w+6%)
+    # same workload (e.g. consecutive jobs, lengths ~w..w+6%)
     # still land in one bucket, and the former 256 grid wasted up to 20%
     # of every LA-proportional cost (walk steps, channels, gathers).
     need = (_round_up(lq, 128), _round_up(la + LA_GROW, 128))
@@ -337,7 +338,7 @@ def _packed_byte_slice(tab, start, L: int):
     cuts the descriptor count 4x. The start&3 phase is
     recovered from four STATIC byte slices with three selects; a
     per-element phase gather here would reintroduce exactly the cost the
-    slice-mode build removed (scripts/ablate_gather_pack.py).
+    slice-mode build removed (a round-5 chip ablation).
     """
     import jax
     import jax.numpy as jnp
@@ -641,13 +642,6 @@ def _merge_round(votes, esc_w, bb, bbw, alen, begin, end, win, ovf, *,
     return new_bb, new_bbw, new_alen, nb, ne, cov, ovf, conv
 
 
-device_round = functools.partial(
-    __import__("jax").jit,
-    static_argnames=("match", "mismatch", "gap", "ins_scale", "Lq",
-                     "n_win", "LA", "pallas", "band_w", "nxt_k",
-                     "detect"))(_round_core)
-
-
 def round_band_width(band_w: int, r: int) -> int:
     """Band width for refinement round ``r`` of a chunk.
 
@@ -661,9 +655,9 @@ def round_band_width(band_w: int, r: int) -> int:
     W=128's wl ~= 63 made it marginal and re-routed 58/96 lambda
     windows (round-5 measurement; Mosaic only needs W % 8, not % 128).
 
-    Shared by every dispatch path (device_chunk_packed, the
-    RACON_TPU_TIMING=1 per-round path, and the convergence scheduler)
-    so profiling and scheduling always execute the production program.
+    Shared by every dispatch path (device_chunk_packed, the decoupled
+    forward/walk pair, and the convergence scheduler), so they all run
+    one band schedule.
     """
     return band_w if (r == 0 or not band_w) else min(band_w, 192)
 
@@ -760,7 +754,7 @@ def device_chunk_packed(job_buf, win_buf, *, match, mismatch, gap,
     at bench scale) and every refinement round plus the output packing
     runs inside a single executable (one synchronized dispatch per
     chunk, not per round). With ``mesh``, each round is the
-    dp-sharded shard_map of device_round_sharded sequenced inside the
+    dp-sharded shard_map from _make_round_fn sequenced inside the
     same program (one psum per round, as before); the job buffer is
     sharded along jobs, the window buffer replicated.
 
@@ -918,27 +912,6 @@ def device_chunk_fwd(job_buf, win_buf, *, match, mismatch, gap,
             bb, bbw, alen, begin, end, ovf, rexec0)
 
 
-@functools.partial(
-    __import__("jax").jit,
-    static_argnames=("match", "mismatch", "gap", "ins_scale", "Lq",
-                     "n_win", "LA", "pallas", "band_w", "mesh", "nxt_k"))
-def device_round_sharded(bb, bbw, alen, begin, end, q, qw8, lq, w_read,
-                         win, ovf, *, match, mismatch, gap, ins_scale, Lq,
-                         n_win, LA, pallas, band_w, mesh, nxt_k=2):
-    """device_round with the job axis sharded over the mesh's "dp" axis.
-
-    Window arrays (anchors, lengths, ovf) stay replicated; each chip
-    aligns and votes its job shard, one psum merges the per-window
-    accumulators, and the (replicated) assembly/compaction runs
-    redundantly per chip — zero-collective except that psum, as windows
-    are independent (SURVEY.md section 7 step 6)."""
-    fn = _make_round_fn(
-        match=match, mismatch=mismatch, gap=gap, ins_scale=ins_scale,
-        Lq=Lq, n_win=n_win, LA=LA, pallas=pallas, band_w=band_w,
-        mesh=mesh, nxt_k=nxt_k)
-    return fn(bb, bbw, alen, begin, end, q, qw8, lq, w_read, win, ovf)
-
-
 def _pack_body(codes, cov, alen, ovf, rounds_exec, rounds_sched):
     """Flatten codes/cov/lengths/overflow into one uint8 buffer for a
     single d2h transfer (each synchronized pull pays a fixed latency).
@@ -960,9 +933,6 @@ def _pack_body(codes, cov, alen, ovf, rounds_exec, rounds_sched):
         ovf.astype(jnp.uint8),
         jax.lax.bitcast_convert_type(rr, jnp.uint8).reshape(-1),
     ])
-
-
-_pack_out = functools.partial(__import__("jax").jit)(_pack_body)
 
 
 def put_chunk_bufs(plan: ChunkPlan, mesh=None) -> Tuple[object, object]:
@@ -1011,45 +981,19 @@ def put_chunk_bufs(plan: ChunkPlan, mesh=None) -> Tuple[object, object]:
 
 
 def dispatch_chunk(plan: ChunkPlan, *, match: int, mismatch: int,
-                   gap: int, ins_scale: float, rounds: int,
-                   stats: Optional[dict] = None, mesh=None,
+                   gap: int, ins_scale: float, rounds: int, mesh=None,
                    bufs: Optional[Tuple[object, object]] = None):
     """Ship a chunk to the device and chain all refinement rounds —
     returns the (still in-flight) packed output array. No host sync:
     the caller may dispatch further chunks before collecting, so h2d of
     chunk i+1 overlaps chunk i's compute.
 
-    ``stats`` (optional dict) accumulates phase wall times under keys
-    "h2d" / "compute" / "d2h" / "chunks". Phase edges force a tiny d2h,
-    so collecting stats serializes the pipeline and adds two host-device
-    round trips per chunk; production runs pass None and pay nothing.
-    RACON_TPU_TIMING=1 additionally prints each round's time to stderr.
-
-    ``bufs`` takes a pre-transferred :func:`put_chunk_bufs` result so a
-    caller can overlap the h2d with earlier compute; None ships the
-    buffers here. Honored on the production path only (the verbose
-    per-round path ships separate arrays).
+    TWO h2d byte buffers, then the whole chunk (all rounds + output
+    packing) as ONE dispatch — per-transfer and per-dispatch latency
+    otherwise dominate. ``bufs`` takes a pre-transferred
+    :func:`put_chunk_bufs` result so a caller can overlap the h2d with
+    earlier compute; None ships the buffers here.
     """
-    import os
-    import sys
-    import time
-    import jax
-    import jax.numpy as jnp
-
-    verbose = envspec.read("RACON_TPU_TIMING") not in ("", "0")
-    collect = stats is not None or verbose
-
-    def sync(x, tag, t0):
-        np.asarray(jnp.ravel(x)[:1])
-        dt = time.perf_counter() - t0
-        if verbose:
-            print(f"[racon_tpu::run_chunk] {tag}: {dt:.3f}s",
-                  file=sys.stderr, flush=True)
-        if stats is not None:
-            key = tag.split("/")[0]
-            stats[key] = stats.get(key, 0.0) + dt
-        return time.perf_counter()
-
     ndp = mesh.shape["dp"] if mesh is not None else 1
     pallas = _use_pallas(plan.B // ndp, plan.Lq, plan.LA)
     band_w = (0 if envspec.read("RACON_TPU_NO_BAND")
@@ -1061,108 +1005,36 @@ def dispatch_chunk(plan: ChunkPlan, *, match: int, mismatch: int,
     from racon_tpu.ops.budget import walk_k_for
     nxt_k = walk_k_for(plan.B // ndp * plan.Lq * band_w) if band_w else 1
     from racon_tpu.ops.colwalk import chain_len
-    from racon_tpu.obs.metrics import record_h2d, registry as obs_registry
+    from racon_tpu.obs.metrics import registry as obs_registry
     obs_registry().set("walk_chain_len",
                        chain_len(plan.LA, nxt_k if band_w else 1))
-    t0 = time.perf_counter()
-    if not verbose:
-        # Production path: TWO h2d byte buffers, then the whole chunk
-        # (all rounds + output packing) as ONE dispatch — per-transfer
-        # and per-dispatch latency otherwise dominate. Stats
-        # collection syncs once on each phase edge.
-        if bufs is None:
-            bufs = put_chunk_bufs(plan, mesh=mesh)
-        job_buf, win_buf = bufs
-        if collect:
-            # Sync on BOTH buffers: device_put is async, and an
-            # in-flight job_buf would otherwise bleed into "compute".
-            t0 = sync(job_buf, "h2d/job", t0)
-            t0 = sync(win_buf, "h2d", t0)
-        from racon_tpu.resilience.retry import call as retry_call
-        # Adaptive early exit: only meaningful with at least one
-        # skippable middle round, and only sound when every non-final
-        # round shares one scale (the replay argument; PoaEngine's
-        # schedule satisfies this by construction).
-        sc = ins_scale if isinstance(ins_scale, tuple) \
-            else (ins_scale,) * rounds
-        adaptive = (envspec.read("RACON_TPU_ADAPTIVE")
-                    not in ("0", "false")
-                    and rounds >= 3 and len(set(sc[:-1])) <= 1)
-        from racon_tpu.ops.budget import dispatch_deadline_s
-        # Deadline scales with the chunk's forward-plane work: B reads
-        # x Lq rows x band (or full LA) columns, once per round.
-        cells = (plan.B * plan.Lq * (band_w if band_w else plan.LA)
-                 * max(rounds, 1))
-        packed = retry_call(
-            "dispatch/chunk", device_chunk_packed, job_buf, win_buf,
-            match=match, mismatch=mismatch, gap=gap, ins_scale=ins_scale,
-            Lq=plan.Lq, n_win=plan.n_win, LA=plan.LA,
-            pallas=pallas, band_w=band_w, rounds=rounds,
-            adaptive=adaptive, mesh=mesh, nxt_k=nxt_k,
-            deadline_s=dispatch_deadline_s(cells), compiles=True)
-        obs_registry().inc("device_dispatches")
-        if collect:
-            t0 = sync(packed, "compute", t0)
-        if stats is not None:
-            stats["chunks"] = stats.get("chunks", 0) + 1
-            stats["_t_pack"] = time.perf_counter()
-        return packed
-
-    # Verbose path: separate arrays + one dispatch per round so each
-    # round's wall time stays attributable (RACON_TPU_TIMING=1).
-    host_args = (plan.bb, plan.bbw, plan.alen, plan.begin, plan.end,
-                 plan.q, plan.qw8, plan.lq, plan.w_read, plan.win)
-    if mesh is None:
-        rnd = device_round
-    else:
-        rnd = functools.partial(device_round_sharded, mesh=mesh)
-
-    def _put():
-        t_put = time.perf_counter()
-        if mesh is None:
-            out = jax.device_put(host_args)
-        else:
-            from jax.sharding import NamedSharding, PartitionSpec
-            rep = NamedSharding(mesh, PartitionSpec())
-            job = NamedSharding(mesh, PartitionSpec("dp"))
-            shardings = (rep, rep, rep, job, job, job, job, job, job,
-                         job)
-            out = tuple(jax.device_put(a, s)
-                        for a, s in zip(host_args, shardings))
-        record_h2d(sum(a.nbytes for a in host_args),
-                   time.perf_counter() - t_put, name="h2d/chunk")
-        return out
-
-    # Same watchdog/retry envelope as the packed path: the verbose
-    # timing path must not reopen the unguarded-transfer hole that
-    # fail-slow hardening closed (choke-point rule CHK001).
+    if bufs is None:
+        bufs = put_chunk_bufs(plan, mesh=mesh)
+    job_buf, win_buf = bufs
     from racon_tpu.resilience.retry import call as retry_call
-    from racon_tpu.ops.budget import transfer_deadline_s
-    dev_args = retry_call(
-        "h2d/chunk", _put,
-        deadline_s=transfer_deadline_s(
-            sum(a.nbytes for a in host_args), "h2d"))
-    bb, bbw, alen, begin, end, q, qw8, lq, w_read, win = dev_args
-    t0 = sync(alen, "h2d", t0)
-    cov = None
-    ovf = jnp.zeros(plan.n_win, dtype=bool)
-    scales = ins_scale if isinstance(ins_scale, tuple) \
+    # Adaptive early exit: only meaningful with at least one
+    # skippable middle round, and only sound when every non-final
+    # round shares one scale (the replay argument; PoaEngine's
+    # schedule satisfies this by construction).
+    sc = ins_scale if isinstance(ins_scale, tuple) \
         else (ins_scale,) * rounds
-    for r in range(rounds):
-        bb, bbw, alen, begin, end, cov, ovf, _ = rnd(
-            bb, bbw, alen, begin, end, q, qw8, lq, w_read, win, ovf,
-            match=match, mismatch=mismatch, gap=gap,
-            ins_scale=scales[r], Lq=plan.Lq, n_win=plan.n_win,
-            LA=plan.LA, pallas=pallas,
-            band_w=round_band_width(band_w, r), nxt_k=nxt_k)
-        obs_registry().inc("device_dispatches")
-        t0 = sync(cov, f"compute/round{r}", t0)
-    if stats is not None:
-        stats["chunks"] = stats.get("chunks", 0) + 1
-        stats["_t_pack"] = time.perf_counter()
-
-    return _pack_out(bb[:-1], cov, alen[:-1], ovf,
-                     jnp.int32(rounds), jnp.int32(rounds))
+    adaptive = (envspec.read("RACON_TPU_ADAPTIVE")
+                not in ("0", "false")
+                and rounds >= 3 and len(set(sc[:-1])) <= 1)
+    from racon_tpu.ops.budget import dispatch_deadline_s
+    # Deadline scales with the chunk's forward-plane work: B reads
+    # x Lq rows x band (or full LA) columns, once per round.
+    cells = (plan.B * plan.Lq * (band_w if band_w else plan.LA)
+             * max(rounds, 1))
+    packed = retry_call(
+        "dispatch/chunk", device_chunk_packed, job_buf, win_buf,
+        match=match, mismatch=mismatch, gap=gap, ins_scale=ins_scale,
+        Lq=plan.Lq, n_win=plan.n_win, LA=plan.LA,
+        pallas=pallas, band_w=band_w, rounds=rounds,
+        adaptive=adaptive, mesh=mesh, nxt_k=nxt_k,
+        deadline_s=dispatch_deadline_s(cells), compiles=True)
+    obs_registry().inc("device_dispatches")
+    return packed
 
 
 def chunk_statics(plan: ChunkPlan, *, ins_scale, rounds: int) -> dict:
@@ -1242,7 +1114,7 @@ def dispatch_chunk_fwd(plan: ChunkPlan, *, match: int, mismatch: int,
     return fwd_out, meta
 
 
-def collect_chunk(plan: ChunkPlan, packed, stats: Optional[dict] = None
+def collect_chunk(plan: ChunkPlan, packed
                   ) -> Tuple[List[Optional[bytes]],
                              List[Optional[np.ndarray]]]:
     """Pull a dispatched chunk's packed output and unpack per window.
@@ -1273,9 +1145,6 @@ def collect_chunk(plan: ChunkPlan, packed, stats: Optional[dict] = None
     out_bytes = 3 * plan.n_win * plan.LA + 5 * plan.n_win + 8
     ph = retry_call("d2h/chunk", _pull,
                     deadline_s=transfer_deadline_s(out_bytes, "d2h"))
-    if stats is not None and "_t_pack" in stats:
-        stats["d2h"] = stats.get("d2h", 0.0) + \
-            (time.perf_counter() - stats.pop("_t_pack"))
     Nw, LA = plan.n_win, plan.LA
     codes_h = ph[:Nw * LA].reshape(Nw, LA)
     cov_h = ph[Nw * LA:3 * Nw * LA].view(np.int16).reshape(Nw, LA)
@@ -1290,9 +1159,6 @@ def collect_chunk(plan: ChunkPlan, packed, stats: Optional[dict] = None
     reg.inc("adaptive_rounds_scheduled", rsch)
     if rex < rsch:
         reg.inc("adaptive_early_exits")
-    if stats is not None:
-        stats["rounds_exec"] = stats.get("rounds_exec", 0) + rex
-        stats["rounds_sched"] = stats.get("rounds_sched", 0) + rsch
 
     out_codes: List[Optional[bytes]] = []
     out_cov: List[Optional[np.ndarray]] = []
@@ -1305,14 +1171,3 @@ def collect_chunk(plan: ChunkPlan, packed, stats: Optional[dict] = None
         out_codes.append(codes_h[wi, :L].tobytes())
         out_cov.append(cov_h[wi, :L].astype(np.int32))
     return out_codes, out_cov
-
-
-def run_chunk(plan: ChunkPlan, *, match: int, mismatch: int, gap: int,
-              ins_scale: float, rounds: int, stats: Optional[dict] = None,
-              mesh=None
-              ) -> Tuple[List[Optional[bytes]], List[Optional[np.ndarray]]]:
-    """dispatch_chunk + collect_chunk, back to back (sequential form)."""
-    packed = dispatch_chunk(plan, match=match, mismatch=mismatch, gap=gap,
-                            ins_scale=ins_scale, rounds=rounds,
-                            stats=stats, mesh=mesh)
-    return collect_chunk(plan, packed, stats=stats)

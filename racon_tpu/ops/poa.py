@@ -153,7 +153,7 @@ class PoaEngine:
         self.backend = backend
         # Optional jax.sharding.Mesh: the device engine shards every
         # chunk's job axis over the mesh's "dp" devices
-        # (racon_tpu/ops/device_poa.py::device_round_sharded); with an
+        # (racon_tpu/ops/device_poa.py::device_chunk_packed); with an
         # "sp" axis, over-budget alignment jobs additionally route
         # through the sequence-parallel NW (see _align).
         self.mesh = mesh
@@ -166,14 +166,11 @@ class PoaEngine:
         self.sp_cell_budget = MAX_DIR_ELEMS // 128
         # OS threads for the native host aligner (reference -t).
         self.threads = threads
-        # Optional dict: run_chunk accumulates phase wall times into it
-        # ("h2d"/"compute"/"d2h"/"chunks"); None = no timing syncs.
-        self.stats = None
         # Filled by the device path when the convergence scheduler runs
         # (racon_tpu/sched/): per-round freeze histogram, survivor
         # fractions, repack overhead. Accumulates across
-        # consensus_windows calls of one run; the polisher logs it and
-        # bench.py serializes it into extras.
+        # consensus_windows calls of one run; the polisher publishes it
+        # to the metrics registry and logs it.
         self.sched_telemetry = None
         self._native = None
 
@@ -195,7 +192,7 @@ class PoaEngine:
             return 0
         # backend "jax": device-resident engine; with a mesh, chunks shard
         # their job axis over the mesh's "dp" devices
-        # (device_poa.device_round_sharded — one psum per round).
+        # (device_poa.device_chunk_packed — one psum per round).
         from racon_tpu.obs.metrics import record_windows
         if self.backend == "jax":
             dev, host, lq_max, la_max = self._partition_device(active)
@@ -251,7 +248,7 @@ class PoaEngine:
         from racon_tpu.ops.device_poa import (run_caps, _bucket_b,
                                               MAX_DIR_ELEMS)
         # One (Lq, LA) cap pair for the whole run (cap-history reuse):
-        # every chunk shares a single compiled device_round executable
+        # every chunk shares a single compiled chunk executable
         # instead of paying a multi-second XLA compile per shape.
         lq_cap, la_cap = run_caps(lq_max, la_max)
         # The dirs tensor that actually bounds chunk size is banded
@@ -345,8 +342,7 @@ class PoaEngine:
                 gap=self.gap,
                 ins_scale=self._round_scales(self.refine_rounds + 1),
                 rounds=self.refine_rounds + 1, mesh=self.mesh,
-                jobs_cap=self.device_batch, stats=self.stats,
-                log=self.log)
+                jobs_cap=self.device_batch, log=self.log)
             for w, c, cv in resolved:
                 w.apply_consensus(
                     decode_bases(np.frombuffer(c, dtype=np.uint8)), cv,
@@ -441,7 +437,7 @@ class PoaEngine:
                                      windows=len(ws), lanes=cur_plan.B,
                                      jobs=cur_plan.n_jobs):
                         codes, covs = sched.run_chunk(
-                            cur_plan, bufs=cur_bufs, stats=self.stats)
+                            cur_plan, bufs=cur_bufs)
                 except RetryExhausted as exc:
                     self._degrade(ws, exc)
                     continue
@@ -449,10 +445,8 @@ class PoaEngine:
         else:
             # Fixed-round pipeline: chunk i+1's h2d + dispatch go out
             # while chunk i still computes (depth 2 bounds in-flight
-            # HBM). Stats collection forces depth 0 (strictly
-            # sequential) so every phase time stays attributable to its
-            # chunk (the pack timestamp lives in the shared stats dict).
-            depth = 0 if self.stats is not None else 2
+            # HBM).
+            depth = 2
             pending: List[tuple] = []
 
             def finish(entry) -> None:
@@ -462,8 +456,7 @@ class PoaEngine:
                 ws, plan, packed, k, t_disp = entry
                 import time as _time
                 try:
-                    codes, covs = collect_chunk(plan, packed,
-                                                stats=self.stats)
+                    codes, covs = collect_chunk(plan, packed)
                 except RetryExhausted as exc:
                     self._degrade(ws, exc)
                     return
@@ -483,8 +476,7 @@ class PoaEngine:
                         gap=self.gap,
                         ins_scale=self._round_scales(
                             self.refine_rounds + 1),
-                        rounds=self.refine_rounds + 1, stats=self.stats,
-                        mesh=self.mesh)
+                        rounds=self.refine_rounds + 1, mesh=self.mesh)
                 except RetryExhausted as exc:
                     self._degrade(ws, exc)
                     continue

@@ -45,7 +45,7 @@ from __future__ import annotations
 
 import os
 from racon_tpu.utils import envspec
-from typing import List, Optional, Tuple
+from typing import List, Tuple
 
 import numpy as np
 
@@ -70,7 +70,7 @@ def _widen(plan) -> None:
 
 def device_redo(windows: List, *, match: int, mismatch: int, gap: int,
                 ins_scale, rounds: int, mesh=None, jobs_cap: int = 2048,
-                stats: Optional[dict] = None, log=None
+                log=None
                 ) -> Tuple[List[Tuple[object, bytes, np.ndarray]], List]:
     """Re-run flagged windows through a wide-band device pass.
 
@@ -122,9 +122,8 @@ def device_redo(windows: List, *, match: int, mismatch: int, gap: int,
                              lanes=plan.B, jobs=plan.n_jobs):
                 packed = dispatch_chunk(
                     plan, match=match, mismatch=mismatch, gap=gap,
-                    ins_scale=ins_scale, rounds=rounds, stats=stats,
-                    mesh=mesh)
-                codes, covs = collect_chunk(plan, packed, stats=stats)
+                    ins_scale=ins_scale, rounds=rounds, mesh=mesh)
+                codes, covs = collect_chunk(plan, packed)
         except RetryExhausted:
             remaining.extend(ws)
             continue

@@ -17,7 +17,7 @@ polisher's chunk planner and the device engine and
       rounds (sched/repack.py) and early-exits whole dispatches when a
       chunk fully converges;
   (d) emits round telemetry (sched/telemetry.py) through
-      utils/logger.py and into bench.py extras.
+      utils/logger.py and into the metrics registry.
 
 ``RACON_TPU_SCHED=0`` falls back to the fixed-round single-dispatch
 engine.

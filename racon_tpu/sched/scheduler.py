@@ -105,15 +105,13 @@ class ConvergenceScheduler:
 
     # ------------------------------------------------------------------ run
 
-    def run_chunk(self, plan, bufs: Optional[Tuple[object, object]] = None,
-                  stats: Optional[dict] = None
+    def run_chunk(self, plan, bufs: Optional[Tuple[object, object]] = None
                   ) -> Tuple[List[Optional[bytes]],
                              List[Optional[np.ndarray]]]:
         """Polish one ChunkPlan; returns collect_chunk's (codes, covs).
 
         ``bufs`` takes a pre-transferred put_chunk result; None ships
-        the buffers here. ``stats`` matches dispatch_chunk's dict
-        ("chunks", then collect_chunk's "d2h").
+        the buffers here.
         """
         from racon_tpu.ops.device_poa import (_use_pallas, collect_chunk,
                                               round_band_width)
@@ -313,7 +311,4 @@ class ConvergenceScheduler:
         packed = sched_pack(out_codes, out_cov, out_total, out_ovf,
                             exec_dev, R)
         reg.inc("device_dispatches")
-        if stats is not None:
-            stats["chunks"] = stats.get("chunks", 0) + 1
-            stats["_t_pack"] = time.perf_counter()
-        return collect_chunk(plan, packed, stats=stats)
+        return collect_chunk(plan, packed)

@@ -4,8 +4,8 @@ Counters only — every value is fed from flags the scheduler already
 pulls to the host for control flow, so recording costs no extra device
 syncs. Reporting routes through the metrics registry
 (racon_tpu/obs/metrics.py): ``publish_sched`` writes the canonical
-``sched_*`` keys the polisher's stderr summary and bench.py's extras
-both read, so the serialized and printed views cannot drift (keys
+``sched_*`` keys the polisher's stderr summary and the trace's metrics
+snapshot both read, so the serialized and printed views cannot drift (keys
 documented in docs/SCHEDULER.md and docs/OBSERVABILITY.md).
 """
 

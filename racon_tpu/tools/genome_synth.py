@@ -1,6 +1,6 @@
 """Seeded genome-polishing jobs: truth, draft, noisy reads, PAF overlaps.
 
-The generator behind scripts/genome_bench.py and chip_smoke.py. From one
+The generator behind chip_smoke.py. From one
 seed it makes a random truth genome, a draft contig with about 2% error
 (substitutions, insertions and deletions in equal parts), reads with
 about 10% error, half of them on the reverse strand, with qualities,

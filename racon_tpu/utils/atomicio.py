@@ -1,10 +1,10 @@
 """Durable file writes: the one place the tmp+rename+fsync dance lives.
 
-Three call sites used to hand-roll (or skip) crash-safe output: bench.py's
-JSON artifact, the tracer's JSONL finalization (obs/trace.py), and the
-resilience checkpoint store (resilience/checkpoint.py). They now share
-these helpers, so every file the toolchain promises to be "complete or
-absent" goes through the same sequence:
+Crash-safe output shares these helpers — the tracer's JSONL
+finalization (obs/trace.py), the resilience checkpoint store
+(resilience/checkpoint.py), the ledger, caches and daemon journals — so
+every file the toolchain promises to be "complete or absent" goes
+through the same sequence:
 
 1. write to ``<path>.tmp.<pid>`` in the destination directory (same
    filesystem, so the rename is atomic),

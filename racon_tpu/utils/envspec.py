@@ -1,7 +1,7 @@
 """Declared registry of every RACON_TPU_* environment gate.
 
-Every environment read of a ``RACON_TPU_*`` name in racon_tpu/,
-scripts/, and bench.py resolves through :func:`read` below, and every
+Every environment read of a ``RACON_TPU_*`` name in racon_tpu/ and
+scripts/ resolves through :func:`read` below, and every
 entry here carries the doc file that holds its row.  The env-contract
 rule in racon_tpu/analysis enforces the triangle in both directions:
 
@@ -160,15 +160,7 @@ declare("RACON_TPU_REDO", "", "flag", "KERNELS.md",
 declare("RACON_TPU_WALK_K", "", "int", "KERNELS.md",
         "column-walk chain length k (1, 2, or 4; default 4)")
 
-# docs/OBSERVABILITY.md — tracing, metrics, bench
-declare("RACON_TPU_BENCH_DP", "", "path", "OBSERVABILITY.md",
-        "dp-scaling bench output path (enables the dp sweep)")
-declare("RACON_TPU_BENCH_E2E_REPS", "3", "int", "OBSERVABILITY.md",
-        "bench.py end-to-end repetitions per measurement")
-declare("RACON_TPU_BENCH_INGEST_MB", "16", "int", "OBSERVABILITY.md",
-        "synthetic corpus size for the ingest micro-bench")
-declare("RACON_TPU_BENCH_OUT", "", "path", "OBSERVABILITY.md",
-        "bench.py JSON results output path")
+# docs/OBSERVABILITY.md — tracing, metrics
 declare("RACON_TPU_DP_TIMEOUT", "600", "float", "OBSERVABILITY.md",
         "per-point timeout for scripts/dp_scaling_bench.py workers")
 declare("RACON_TPU_FLIGHT_EVENTS", "", "int", "OBSERVABILITY.md",
@@ -179,8 +171,6 @@ declare("RACON_TPU_OBS_DIR", "", "path", "OBSERVABILITY.md",
         "per-worker metrics snapshot directory (fleet obs plane)")
 declare("RACON_TPU_OBS_FLUSH_S", "", "float", "OBSERVABILITY.md",
         "metrics snapshot flush interval override")
-declare("RACON_TPU_TIMING", "", "flag", "OBSERVABILITY.md",
-        "verbose per-round timing (separate dispatch per round)")
 declare("RACON_TPU_TRACE", "", "path", "OBSERVABILITY.md",
         "span trace output directory (JSONL tracer gate)")
 declare("RACON_TPU_TRACE_CTX", "", "str", "OBSERVABILITY.md",

@@ -1,5 +1,5 @@
-"""Measured dp-scaling curve: 1/2/4/8-worker ledger fleets over the
-bench genome (closes ROADMAP item 2's open half).
+"""Measured dp-scaling curve: 1/2/4/8-worker ledger fleets over one
+synthetic genome.
 
 For each requested worker count N this script runs a complete
 ``--ledger-dir`` fleet of N real CLI subprocesses over the same
@@ -12,11 +12,9 @@ fleet's wall clock. The curve is emitted as one JSON object::
      "dp_windows_per_sec_2": ..., "dp_windows_per_sec_4": ...,
      "dp_scaling_efficiency": rate_N / (N * rate_1), ...}
 
-Publish it through bench.py (metric_version 10) by pointing
-``RACON_TPU_BENCH_DP`` at the artifact::
+``--out <path>`` writes it to a file as well::
 
     python scripts/dp_scaling_bench.py --out /tmp/dp.json
-    RACON_TPU_BENCH_DP=/tmp/dp.json python bench.py
 
 Worker counts: ``--workers 1,2,4,8`` (default ``auto`` = 1,2,4 plus 8
 when the host has >= 8 CPUs). An explicitly requested count the host

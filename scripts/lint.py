@@ -2,7 +2,7 @@
 """Contract linter driver.
 
 Runs every rule in racon_tpu.analysis.rules.ALL_RULES over the repo
-(racon_tpu/, scripts/, bench.py), subtracts the baseline, and prints a
+(racon_tpu/, scripts/), subtracts the baseline, and prints a
 byte-stable report plus a ``lint_findings_total=...`` summary line.
 
     python scripts/lint.py              # report, exit 0 always

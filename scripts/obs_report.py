@@ -1,9 +1,8 @@
 """Render a racon_tpu JSONL trace into a per-stage breakdown table.
 
-The manual workflow this automates: per-stage delta tables were
-hand-assembled from RACON_TPU_TIMING stderr lines and stopwatch
-arithmetic every perf round. A trace (RACON_TPU_TRACE=<path> or
-``--trace``) now carries the same decomposition; this script renders it.
+A trace (RACON_TPU_TRACE=<path> or ``--trace``) carries the run's
+per-stage decomposition (spans) and its closing metrics snapshot; this
+script renders both.
 
 Usage:
     python scripts/obs_report.py TRACE.jsonl            # breakdown table
@@ -659,10 +658,8 @@ def _render_cache(m, by_kind, out) -> None:
 
 def _render_ava(m, out) -> None:
     """The "ava:" section: the shape-bucket plan (targets, buckets vs
-    the compile budget, quantum, padding overhead) and — when the ava
-    bench ran — throughput, peak RSS, and the v2 manifest's bytes per
-    committed target (docs/AVA.md). kC runs record no ``ava_*`` keys
-    and print nothing."""
+    the compile budget, quantum, padding overhead; docs/AVA.md). kC
+    runs record no ``ava_*`` keys and print nothing."""
     m = m or {}
     if not any(k.startswith("ava_") for k in m):
         return
@@ -672,12 +669,6 @@ def _render_ava(m, out) -> None:
           f"quantum={int(m.get('ava_quantum', 0))}  "
           f"pad_frac={float(m.get('ava_pad_frac', 0) or 0):.4f}",
           file=out)
-    if m.get("ava_reads_per_sec") is not None:
-        print(f"  reads/s={float(m.get('ava_reads_per_sec', 0)):.1f}  "
-              f"peak_rss={float(m.get('ava_peak_rss_mb', 0)):.1f}MB  "
-              f"manifest_bytes/target="
-              f"{float(m.get('ava_manifest_bytes_per_target', 0)):.2f}",
-              file=out)
 
 
 def _render_fleet(fleet_dir: str, out) -> None:

@@ -79,10 +79,10 @@ def test_band_scores_and_paths_match_oracle(scoring):
 
 def test_engine_band_matches_full_width():
     """End-to-end: banded and full-width device paths produce identical
-    consensus on bench-like windows (band covers the optimum, identical
-    tie-breaking)."""
+    consensus on synthetic noisy windows (band covers the optimum,
+    identical tie-breaking)."""
     import os
-    from bench import build_windows
+    from synth_windows import build_windows
     from racon_tpu.ops.poa import PoaEngine
 
     ws_band = build_windows(8, 6, 200, seed=13)

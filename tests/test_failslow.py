@@ -19,7 +19,8 @@ from racon_tpu.obs import metrics as obs_metrics
 from racon_tpu.ops import budget
 from racon_tpu.resilience import faults, retry, watchdog
 
-BASES = np.frombuffer(b"ACGT", np.uint8)
+from synth_windows import BASES, build_subdel_windows, mutate_subdel
+
 
 #: Every env knob this subsystem reads — scrubbed around each test so
 #: an operator shell (or a neighbouring test) can't leak configuration.
@@ -217,33 +218,6 @@ def test_retry_detects_hang_and_recovers():
 # --------------------------------------------------------- chaos drill
 
 
-def _mutate(rng, truth):
-    out = []
-    for b in truth:
-        r = rng.random()
-        if r < 0.04:
-            continue
-        out.append(int(BASES[rng.integers(0, 4)]) if r < 0.08 else int(b))
-    return bytes(out)
-
-
-def _build_windows(n, seed=0, coverage=5, wlen=80):
-    from racon_tpu.models.window import Window, WindowType
-    rng = np.random.default_rng(seed)
-    ws = []
-    for i in range(n):
-        truth = BASES[rng.integers(0, 4, wlen)]
-        backbone = _mutate(rng, truth)
-        qual = bytes(rng.integers(43, 63, len(backbone), dtype=np.uint8))
-        w = Window(i, i % 3, WindowType.TGS, backbone, qual)
-        for _ in range(coverage):
-            lay = _mutate(rng, truth)
-            lq = bytes(rng.integers(43, 63, len(lay), dtype=np.uint8))
-            w.add_layer(lay, lq, 0, len(backbone) - 1)
-        ws.append(w)
-    return ws
-
-
 _CHAOS_SITES = ("h2d/chunk", "dispatch/chunk", "d2h/chunk")
 _CHAOS_ACTIONS = ("", "!hang=0.4", "!stall=0.2")   # "" = raise
 
@@ -258,7 +232,7 @@ def test_chaos_mixed_faults_byte_identical(monkeypatch):
 
     from racon_tpu.ops.poa import PoaEngine
 
-    clean = _build_windows(10, seed=7)
+    clean = build_subdel_windows(10, seed=7)
     PoaEngine(backend="jax", log=io.StringIO()).consensus_windows(clean)
     want = [w.consensus for w in clean]
 
@@ -273,7 +247,7 @@ def test_chaos_mixed_faults_byte_identical(monkeypatch):
             for site in rng.sample(_CHAOS_SITES,
                                    rng.randint(1, len(_CHAOS_SITES))))
         faults.configure(spec)
-        ws = _build_windows(10, seed=7)
+        ws = build_subdel_windows(10, seed=7)
         result = {}
 
         def run(ws=ws, result=result):
@@ -305,10 +279,10 @@ def _write_inputs(d, n_contigs=2, n_reads=6, clen=300):
     drafts, reads, paf = [], [], []
     for ci in range(n_contigs):
         truth = BASES[rng.integers(0, 4, clen)]
-        draft = _mutate(rng, truth)
+        draft = mutate_subdel(rng, truth)
         drafts.append(b">c%d\n%s\n" % (ci, draft))
         for i in range(n_reads):
-            r = _mutate(rng, truth)
+            r = mutate_subdel(rng, truth)
             name = f"c{ci}r{i}"
             reads.append(b">" + name.encode() + b"\n" + r + b"\n")
             paf.append(f"{name}\t{len(r)}\t0\t{len(r)}\t+\tc{ci}"

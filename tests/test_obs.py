@@ -191,17 +191,17 @@ def test_transfer_extras_empty():
 
 def test_redo_extras_derivation():
     reg = obs_metrics.MetricsRegistry()
-    assert obs_metrics.redo_extras(reg) == {}
+    assert reg.snapshot() == {}
     reg.set("walk_chain_len", 161)
     # The chain gauge reports even on runs where no window ever flags.
-    assert obs_metrics.redo_extras(reg) == {"walk_chain_len": 161}
+    assert reg.snapshot() == {"walk_chain_len": 161}
     obs_metrics.record_redo(3, 0, reg=reg)
     obs_metrics.record_redo(1, 1, reg=reg)
-    ex = obs_metrics.redo_extras(reg)
-    assert ex["redo_passes"] == 2
-    assert ex["redo_device_windows"] == 4
-    assert ex["redo_host_windows"] == 1
-    assert ex["walk_chain_len"] == 161
+    snap = reg.snapshot()
+    assert snap["redo_passes"] == 2
+    assert snap["redo_device_windows"] == 4
+    assert snap["redo_host_windows"] == 1
+    assert snap["walk_chain_len"] == 161
 
 
 def _telem():

@@ -100,10 +100,10 @@ def test_engine_with_mesh_matches_engine_without():
 
 
 def test_sharded_device_engine_noisy_windows():
-    """device_round_sharded on the 8-device mesh must reproduce the
+    """The dp-sharded chunk program on the 8-device mesh must reproduce the
     single-device engine bit-for-bit on realistic noisy windows (psum'd
     vote accumulators, jobs of one window spread across shards)."""
-    from bench import build_windows
+    from synth_windows import build_windows
     from racon_tpu.ops.poa import PoaEngine
 
     ws_ref = build_windows(10, 6, 130, seed=11)

@@ -22,7 +22,7 @@ from racon_tpu.pipeline import (BoundedQueue, Pipeline, PipelineAborted,
                                 pipeline_depth, pipeline_enabled)
 from racon_tpu.pipeline.streaming import SliceTracker, stream_consensus
 
-BASES = np.frombuffer(b"ACGT", np.uint8)
+from synth_windows import BASES, build_stream_windows, mutate_indel
 
 
 @pytest.fixture(autouse=True)
@@ -238,39 +238,6 @@ def test_gating_depth(monkeypatch):
 # ----------------------------------------------- streaming differential
 
 
-def _mutate(rng, truth):
-    out = []
-    for b in truth:
-        r = rng.random()
-        if r < 0.04:
-            continue                       # deletion
-        out.append(int(BASES[rng.integers(0, 4)]) if r < 0.08 else int(b))
-        if r > 0.96:
-            out.append(int(BASES[rng.integers(0, 4)]))  # insertion
-    return bytes(out)
-
-
-def _build_windows(n, seed=0, coverage=5, wlen=80):
-    """Synthetic polishing windows with trivial (no-layer) windows
-    sprinkled in, so the stream exercises both the inline backbone path
-    and device chunks. Same seed => bit-identical window set."""
-    from racon_tpu.models.window import Window, WindowType
-    rng = np.random.default_rng(seed)
-    ws = []
-    for i in range(n):
-        truth = BASES[rng.integers(0, 4, wlen)]
-        backbone = _mutate(rng, truth)
-        qual = bytes(rng.integers(43, 63, len(backbone), dtype=np.uint8))
-        w = Window(i, i % 7, WindowType.TGS, backbone, qual)
-        cov = 0 if i % 9 == 8 else coverage
-        for _ in range(cov):
-            lay = _mutate(rng, truth)
-            lq = bytes(rng.integers(43, 63, len(lay), dtype=np.uint8))
-            w.add_layer(lay, lq, 0, len(backbone) - 1)
-        ws.append(w)
-    return ws
-
-
 def test_stream_consensus_bit_identical_to_serial():
     """The tentpole contract: the streaming executor shares the serial
     engine's slice planning, so its consensi are bit-identical, and its
@@ -278,10 +245,10 @@ def test_stream_consensus_bit_identical_to_serial():
     from racon_tpu.obs import metrics as obs_metrics
     from racon_tpu.ops.poa import PoaEngine
 
-    serial = _build_windows(24, seed=42)
+    serial = build_stream_windows(24, seed=42)
     PoaEngine(backend="jax").consensus_windows(serial)
 
-    streamed = _build_windows(24, seed=42)
+    streamed = build_stream_windows(24, seed=42)
     obs_metrics.reset()
     ranges = list(stream_consensus(PoaEngine(backend="jax"), streamed,
                                    chunk=8, depth=2))
@@ -303,7 +270,7 @@ def test_stream_consensus_bit_identical_to_serial():
 
 def test_stream_consensus_abandoned_generator_closes_cleanly():
     from racon_tpu.ops.poa import PoaEngine
-    ws = _build_windows(24, seed=7)
+    ws = build_stream_windows(24, seed=7)
     gen = stream_consensus(PoaEngine(backend="jax"), ws, chunk=4, depth=1)
     next(gen)
     t0 = time.perf_counter()
@@ -324,10 +291,10 @@ def _write_two_contig_inputs(d, n_reads=8, clen=400):
     drafts, reads, paf = [], [], []
     for ci in (1, 2):
         truth = BASES[rng.integers(0, 4, clen)]
-        draft = _mutate(rng, truth)
+        draft = mutate_indel(rng, truth)
         drafts.append(b">c%d\n%s\n" % (ci, draft))
         for i in range(n_reads):
-            r = _mutate(rng, truth)
+            r = mutate_indel(rng, truth)
             name = f"c{ci}r{i}"
             reads.append(b">" + name.encode() + b"\n" + r + b"\n")
             paf.append(f"{name}\t{len(r)}\t0\t{len(r)}\t+\tc{ci}"

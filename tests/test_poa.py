@@ -112,3 +112,22 @@ def test_tgs_trim_drops_uncovered_flanks():
     PoaEngine(backend="native").consensus_windows([w])
     # Coverage >= n_layers//2 only inside [50, 100).
     assert len(w.consensus) <= 60
+
+
+def test_device_engine_two_shapes_one_process():
+    """Differently shaped device-engine runs in sequence in one process.
+
+    A module-level jax.Array constant once lowered as a hoisted
+    executable parameter that the execution path then under-supplied
+    (INVALID_ARGUMENT: "Execution supplied N buffers but compiled
+    program expected N+1") on the second shape. The geometries give
+    each run its own Lq/LA padding buckets, so each compiles its own
+    executables; the failure reproduced on the CPU backend too."""
+    from synth_windows import build_windows
+
+    for n, cov, wlen, seed in ((6, 6, 120, 3), (5, 8, 150, 7),
+                               (4, 10, 260, 11)):
+        ws = build_windows(n, cov, wlen, seed=seed)
+        eng = PoaEngine(backend="jax")
+        assert eng.consensus_windows(ws) == n
+        assert all(w.consensus for w in ws)

@@ -13,7 +13,7 @@ from racon_tpu.obs import metrics as obs_metrics
 from racon_tpu.resilience import checkpoint as ckpt
 from racon_tpu.resilience import faults, retry
 
-BASES = np.frombuffer(b"ACGT", np.uint8)
+from synth_windows import BASES, build_subdel_windows, mutate_subdel
 
 
 @pytest.fixture(autouse=True)
@@ -487,45 +487,18 @@ def test_v1_stores_unaffected_by_v2_code(tmp_path):
 # ------------------------------------------- degradation + CLI integration
 
 
-def _mutate(rng, truth):
-    out = []
-    for b in truth:
-        r = rng.random()
-        if r < 0.04:
-            continue
-        out.append(int(BASES[rng.integers(0, 4)]) if r < 0.08 else int(b))
-    return bytes(out)
-
-
-def _build_windows(n, seed=0, coverage=5, wlen=80):
-    from racon_tpu.models.window import Window, WindowType
-    rng = np.random.default_rng(seed)
-    ws = []
-    for i in range(n):
-        truth = BASES[rng.integers(0, 4, wlen)]
-        backbone = _mutate(rng, truth)
-        qual = bytes(rng.integers(43, 63, len(backbone), dtype=np.uint8))
-        w = Window(i, i % 3, WindowType.TGS, backbone, qual)
-        for _ in range(coverage):
-            lay = _mutate(rng, truth)
-            lq = bytes(rng.integers(43, 63, len(lay), dtype=np.uint8))
-            w.add_layer(lay, lq, 0, len(backbone) - 1)
-        ws.append(w)
-    return ws
-
-
 def test_degradation_to_host_is_bit_identical():
     """Retry exhaustion at a transfer site must not change output: the
     chunk's windows reroute to the host path, which is bit-identical to
     the device path by design."""
     from racon_tpu.ops.poa import PoaEngine
 
-    clean = _build_windows(8, seed=5)
+    clean = build_subdel_windows(8, seed=5)
     PoaEngine(backend="jax", log=io.StringIO()).consensus_windows(clean)
 
     retry.configure(retry.RetryPolicy(attempts=2, base=0.0, jitter=0.0))
     faults.configure("h2d/chunk:p=1.0")     # every upload attempt fails
-    degraded = _build_windows(8, seed=5)
+    degraded = build_subdel_windows(8, seed=5)
     log = io.StringIO()
     PoaEngine(backend="jax", log=log).consensus_windows(degraded)
 
@@ -537,30 +510,28 @@ def test_degradation_to_host_is_bit_identical():
     assert snap["res_degraded_windows"] >= 1
 
 
-def test_verbose_timing_path_h2d_retry_envelope(monkeypatch):
-    """Pin the choke-point fix: the RACON_TPU_TIMING=1 per-round path
-    shipped its arrays through a bare jax.device_put with no
-    fault/retry/deadline envelope, so a transfer fault there bypassed
-    the whole resilience layer. Now the upload retries like the packed
-    path: a one-shot h2d/chunk fault is absorbed, output unchanged."""
+def test_verbose_timing_path_h2d_retry_envelope():
+    """A transient upload fault on the chunk path is retried with
+    unchanged output: a one-shot h2d/chunk fault on the put_chunk_bufs
+    upload is absorbed by one retry inside its watchdog envelope,
+    without exhausting the budget or degrading to the host. The name
+    predates the single chunk dispatch path."""
     from racon_tpu.ops.poa import PoaEngine
 
-    clean = _build_windows(6, seed=7)
+    clean = build_subdel_windows(6, seed=7)
     PoaEngine(backend="jax", log=io.StringIO()).consensus_windows(clean)
     obs_metrics.reset()
 
-    monkeypatch.setenv("RACON_TPU_TIMING", "1")
     retry.configure(retry.RetryPolicy(attempts=3, base=0.0, jitter=0.0))
     faults.configure("h2d/chunk:0")
-    timed = _build_windows(6, seed=7)
-    with contextlib.redirect_stderr(io.StringIO()):
-        PoaEngine(backend="jax",
-                  log=io.StringIO()).consensus_windows(timed)
+    faulted = build_subdel_windows(6, seed=7)
+    PoaEngine(backend="jax", log=io.StringIO()).consensus_windows(faulted)
 
-    assert [w.consensus for w in timed] == \
+    assert [w.consensus for w in faulted] == \
         [w.consensus for w in clean]
     snap = obs_metrics.registry().snapshot()
     assert snap["res_fault_injected_total"] >= 1
+    assert snap["res_fault_site_h2d_chunk"] >= 1
     assert snap["res_retry_total"] >= 1
     assert "res_retry_exhausted" not in snap
 
@@ -605,7 +576,7 @@ def test_compile_refusal_in_chunk_dispatch_raises(monkeypatch, sched):
     log = io.StringIO()
     with pytest.raises(JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
         PoaEngine(backend="jax", log=log).consensus_windows(
-            _build_windows(4, seed=3))
+            build_subdel_windows(4, seed=3))
     assert calls == [1]
     snap = obs_metrics.registry().snapshot()
     assert "res_retry_total" not in snap
@@ -642,10 +613,10 @@ def _write_inputs(d, n_contigs=2, n_reads=6, clen=300):
     drafts, reads, paf = [], [], []
     for ci in range(n_contigs):
         truth = BASES[rng.integers(0, 4, clen)]
-        draft = _mutate(rng, truth)
+        draft = mutate_subdel(rng, truth)
         drafts.append(b">c%d\n%s\n" % (ci, draft))
         for i in range(n_reads):
-            r = _mutate(rng, truth)
+            r = mutate_subdel(rng, truth)
             name = f"c{ci}r{i}"
             reads.append(b">" + name.encode() + b"\n" + r + b"\n")
             paf.append(f"{name}\t{len(r)}\t0\t{len(r)}\t+\tc{ci}"

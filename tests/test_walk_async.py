@@ -17,7 +17,8 @@ from racon_tpu.obs import metrics as obs_metrics
 from racon_tpu.pipeline.streaming import stream_consensus
 from racon_tpu.resilience import faults, retry, watchdog
 
-BASES = np.frombuffer(b"ACGT", np.uint8)
+from synth_windows import build_stream_windows
+
 
 _ENVS = ("RACON_TPU_WALK_ASYNC", "RACON_TPU_WALK_QUEUE",
          "RACON_TPU_SCHED", "RACON_TPU_ADAPTIVE", "RACON_TPU_PIPELINE",
@@ -41,44 +42,11 @@ def walk_sandbox(monkeypatch):
     watchdog.reset()
 
 
-def _mutate(rng, truth):
-    out = []
-    for b in truth:
-        r = rng.random()
-        if r < 0.04:
-            continue
-        out.append(int(BASES[rng.integers(0, 4)]) if r < 0.08 else int(b))
-        if r > 0.96:
-            out.append(int(BASES[rng.integers(0, 4)]))
-    return bytes(out)
-
-
-def _build_windows(n, seed=0, coverage=5, wlen=80):
-    """tests/test_pipeline.py's synthetic window set: trivial windows
-    sprinkled in so the stream exercises the inline backbone path and
-    device chunks alike."""
-    from racon_tpu.models.window import Window, WindowType
-    rng = np.random.default_rng(seed)
-    ws = []
-    for i in range(n):
-        truth = BASES[rng.integers(0, 4, wlen)]
-        backbone = _mutate(rng, truth)
-        qual = bytes(rng.integers(43, 63, len(backbone), dtype=np.uint8))
-        w = Window(i, i % 7, WindowType.TGS, backbone, qual)
-        cov = 0 if i % 9 == 8 else coverage
-        for _ in range(cov):
-            lay = _mutate(rng, truth)
-            lq = bytes(rng.integers(43, 63, len(lay), dtype=np.uint8))
-            w.add_layer(lay, lq, 0, len(backbone) - 1)
-        ws.append(w)
-    return ws
-
-
 def _chunk_fixture(seed=1):
     """One packed ChunkPlan plus the engine's dispatch parameters."""
     from racon_tpu.ops.poa import PoaEngine
     eng = PoaEngine(backend="jax")
-    ws = [w for w in _build_windows(12, seed=seed) if w.n_layers >= 2]
+    ws = [w for w in build_stream_windows(12, seed=seed) if w.n_layers >= 2]
     dev, _host, lq_max, la_max = eng._partition_device(ws)
     sp = eng._plan_device_slice(dev, lq_max, la_max)
     assert sp.groups
@@ -164,13 +132,13 @@ def test_stream_walk_async_bit_identical_and_counted(monkeypatch):
     from racon_tpu.ops.poa import PoaEngine
 
     monkeypatch.setenv("RACON_TPU_SCHED", "0")
-    serial = _build_windows(24, seed=3)
+    serial = build_stream_windows(24, seed=3)
     PoaEngine(backend="jax").consensus_windows(serial)
     ref = [w.consensus for w in serial]
 
     monkeypatch.setenv("RACON_TPU_WALK_ASYNC", "1")
     obs_metrics.reset()
-    assert _stream(_build_windows(24, seed=3)) == ref
+    assert _stream(build_stream_windows(24, seed=3)) == ref
     snap = obs_metrics.registry().snapshot()
     assert snap["walk_async_enabled"] == 1
     assert snap["walk_dispatches"] >= 1
@@ -181,7 +149,7 @@ def test_stream_walk_async_bit_identical_and_counted(monkeypatch):
 
     monkeypatch.setenv("RACON_TPU_WALK_ASYNC", "0")
     obs_metrics.reset()
-    assert _stream(_build_windows(24, seed=3)) == ref
+    assert _stream(build_stream_windows(24, seed=3)) == ref
     snap = obs_metrics.registry().snapshot()
     assert snap["walk_async_enabled"] == 0
     assert snap["walk_dispatches"] == 0
@@ -197,13 +165,13 @@ def test_stream_matrix_bit_identical(monkeypatch, sched, adaptive):
 
     monkeypatch.setenv("RACON_TPU_SCHED", sched)
     monkeypatch.setenv("RACON_TPU_ADAPTIVE", adaptive)
-    serial = _build_windows(16, seed=9)
+    serial = build_stream_windows(16, seed=9)
     PoaEngine(backend="jax").consensus_windows(serial)
     ref = [w.consensus for w in serial]
     for walk in ("1", "0"):
         monkeypatch.setenv("RACON_TPU_WALK_ASYNC", walk)
         obs_metrics.reset()
-        assert _stream(_build_windows(16, seed=9)) == ref, \
+        assert _stream(build_stream_windows(16, seed=9)) == ref, \
             f"SCHED={sched} ADAPTIVE={adaptive} WALK_ASYNC={walk}"
         snap = obs_metrics.registry().snapshot()
         if sched == "1" or walk == "0":
@@ -218,7 +186,7 @@ def test_single_chunk_stream_falls_back_fused(monkeypatch):
     rule keeps it fused and the gauges say so."""
     monkeypatch.setenv("RACON_TPU_SCHED", "0")
     monkeypatch.setenv("RACON_TPU_WALK_ASYNC", "1")
-    ws = _build_windows(8, seed=13)
+    ws = build_stream_windows(8, seed=13)
     _stream(ws, chunk=32)
     snap = obs_metrics.registry().snapshot()
     assert snap["walk_dispatches"] == 0
@@ -231,7 +199,7 @@ def test_walk_queue_zero_disables_decoupling(monkeypatch):
     monkeypatch.setenv("RACON_TPU_SCHED", "0")
     monkeypatch.setenv("RACON_TPU_WALK_ASYNC", "1")
     monkeypatch.setenv("RACON_TPU_WALK_QUEUE", "0")
-    _stream(_build_windows(24, seed=3))
+    _stream(build_stream_windows(24, seed=3))
     snap = obs_metrics.registry().snapshot()
     assert snap["walk_dispatches"] == 0
     assert snap["walk_async_enabled"] == 0
@@ -251,13 +219,13 @@ def test_walk_stage_stall_detected_and_recovered(monkeypatch):
     monkeypatch.setenv("RACON_TPU_SCHED", "0")
     monkeypatch.setenv("RACON_TPU_WALK_ASYNC", "1")
     monkeypatch.setenv("RACON_TPU_STALL_S", "0.5")
-    serial = _build_windows(24, seed=11)
+    serial = build_stream_windows(24, seed=11)
     PoaEngine(backend="jax").consensus_windows(serial)
     ref = [w.consensus for w in serial]
 
     faults.configure("pipe/walk:0!hang=3")
     obs_metrics.reset()
-    assert _stream(_build_windows(24, seed=11)) == ref
+    assert _stream(build_stream_windows(24, seed=11)) == ref
     snap = obs_metrics.registry().snapshot()
     assert snap["pipe_stall_events"] >= 1
     assert watchdog.health_snapshot()["pipeline_stalls"] >= 1
